@@ -192,6 +192,8 @@ def run(smoke: bool = False, trace: bool = False) -> List[Row]:
 
 
 def main() -> int:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",

@@ -25,6 +25,8 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     only = sys.argv[1:] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
     failures = []

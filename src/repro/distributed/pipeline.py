@@ -31,24 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version compat: ``jax.shard_map`` (keyword ``check_vma``, or
-    ``check_rep`` on 0.5/0.6) vs ``jax.experimental.shard_map.shard_map``
-    (0.4.x, ``check_rep``).  Replication checking is off in all cases —
-    the final all-gather makes the output replicated but the checker
-    can't prove it."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:        # jax with shard_map but pre-rename kwarg
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def pipeline_apply(stage_fn: Callable, stage_params, microbatches,
                    *, mesh, axis: str = "pod"):
     """Run ``microbatches`` [M, ...] through all pipeline stages.
@@ -95,11 +77,13 @@ def pipeline_apply(stage_fn: Callable, stage_params, microbatches,
         return outs
 
     pspec = P(axis)
-    out = _shard_map(
+    # replication checking is off: the final all-gather makes the output
+    # replicated, but the checker cannot prove it
+    out = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: pspec, stage_params),
                   P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )(stage_params, microbatches)
     return out
 
@@ -107,8 +91,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, microbatches,
 def _self_check():
     import os
     import numpy as np
-    from ..launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    from ..launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
 
     # 4-layer MLP, 2 stages x 2 layers
     rng = np.random.default_rng(0)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
 
 F32 = jnp.float32
 NEG_INF = -2.0e38
@@ -105,7 +104,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         scratch_shapes=[pltpu.VMEM((bq,), F32),
                         pltpu.VMEM((bq,), F32),
                         pltpu.VMEM((bq, hd), F32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import tpu_compiler_params
 
 F32 = jnp.float32
 
@@ -58,7 +57,7 @@ def lsh_signature(blocks, proj, bias, *, r: float, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bh), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, num_hashes), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bh), F32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

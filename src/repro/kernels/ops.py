@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On TPU the Pallas path compiles natively; on CPU (this container) the
-kernels execute through ``interpret=True`` — same kernel body, Python
-interpretation, used by the allclose test sweeps against ``ref.py``.
-Wrappers handle padding to tile multiples and unpadding.
+On TPU the Pallas path compiles natively; on CPU the kernels execute
+through ``interpret=True`` — same kernel body, lowered for the CPU, used
+by the allclose test sweeps against ``ref.py``.  Any other backend
+raises: a GPU must not silently interpret TPU kernels.  Wrappers handle
+padding to tile multiples and unpadding.
 """
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from ._compat import tpu_compiler_params  # re-export: version-compat shim
+from .dedup_embedding import ROW_TILE
 from .dedup_embedding import dedup_embedding as _dedup_embedding
 from .dedup_matmul import dedup_matmul as _dedup_matmul
 from .flash_attention import flash_attention as _flash_attention
@@ -19,7 +20,14 @@ from .lsh_signature import lsh_signature as _lsh_signature
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU (interpret mode), False on the TPU; raises on any
+    other backend, where the TPU kernels neither compile nor should be
+    interpreted in silence."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on the "
+                           f"{backend!r} backend")
+    return backend == "cpu"
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -44,29 +52,38 @@ def dedup_matmul(x, pool, block_map, bm: int = 128, out_dtype=None):
 
 
 def dedup_embedding(ids, pool, row_block_map):
-    lead = ids.shape
-    out = _dedup_embedding(ids.reshape(-1), pool, row_block_map,
-                           interpret=_interpret())
-    return out.reshape(lead + (out.shape[-1],))
+    """Row-block embedding: pool [n_distinct, bv, D] with row blocks
+    spanning the full model dimension; row_block_map [V/bv].  ids of any
+    shape -> [..., D]."""
+    return dedup_embedding_striped(ids, pool, row_block_map[:, None],
+                                   block_shape=pool.shape[1:])
 
 
-def dedup_embedding_striped(ids, pool, block_map, width=None):
+def dedup_embedding_striped(ids, pool, block_map, block_shape, width=None):
     """Row gather from a 2-D virtual tensor stored as ``(bh, bw)`` blocks.
 
-    The plain ``dedup_embedding`` kernel assumes row blocks spanning the
-    full model dimension (``pool [n, bv, D]``).  Storage blocks are square
-    tiles, so a row of the virtual tensor crosses ``gw`` column stripes:
-    this adapter runs the kernel once per stripe against the same resident
-    pool — each stripe's ``block_map[:, j]`` is its own row-block map —
-    and concatenates, trimming the ragged last stripe to ``width``.
+    Storage blocks are square tiles, so a row of the virtual tensor
+    crosses ``gw`` column stripes; the kernel gathers every stripe of a
+    row in one call (``block_map[:, j]`` is stripe ``j``'s row-block
+    map) and the ragged last stripe is trimmed to ``width``.
 
-    ids [B]; pool [n_blocks, bh, bw]; block_map [gh, gw] int32.
-    Returns [B, width or gw*bw].
+    ids: any shape; pool: the distinct blocks end to end (``[n, bh, bw]``
+    or the device slab's lane-row layout); block_map [gh, gw] int32.
+    Returns [..., width or gw*bw].
     """
-    gh, gw = block_map.shape
-    outs = [dedup_embedding(ids, pool, block_map[:, j]) for j in range(gw)]
-    out = outs[0] if gw == 1 else jnp.concatenate(outs, axis=1)
-    return out if width is None else out[:, :width]
+    lead = ids.shape
+    flat = ids.reshape(-1)
+    # pad with a requested id, not id 0: under partial residency row 0's
+    # block may be a -1 hole, and a DMA from it would read out of bounds
+    pad = (-flat.shape[0]) % ROW_TILE
+    flat = jnp.concatenate([flat, jnp.broadcast_to(flat[:1], (pad,))])
+    out = _dedup_embedding(flat, pool, block_map,
+                           block_shape=tuple(int(b) for b in block_shape),
+                           interpret=_interpret())
+    out = out[:out.shape[0] - pad]
+    if width is not None:
+        out = out[:, :width]
+    return out.reshape(lead + (out.shape[-1],))
 
 
 def lsh_signature(blocks, proj, bias, r: float):
@@ -103,4 +120,4 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 
 __all__ = ["dedup_matmul", "dedup_embedding", "dedup_embedding_striped",
-           "lsh_signature", "flash_attention", "ref", "tpu_compiler_params"]
+           "lsh_signature", "flash_attention", "ref"]

@@ -1,11 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (jax locks the device
-# count at first init).  REPRO_DRYRUN_DEVICES overrides for mini CI runs.
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                               + os.environ["REPRO_DRYRUN_DEVICES"])
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh from ShapeDtypeStruct inputs only (no allocation), and
 record memory_analysis / cost_analysis / collective schedule for the
@@ -18,11 +10,21 @@ Usage:
 """
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
 import traceback
 from typing import Dict
+
+if __name__ == "__main__":        # must precede the jax import below
+    # The dry-run describes a pod on CPU host devices: it never takes a
+    # TPU, and jax locks the device count at first init.
+    # REPRO_DRYRUN_DEVICES overrides the count for mini CI runs.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count="
+        + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
 
 import jax
 import jax.numpy as jnp
@@ -34,8 +36,9 @@ from ..distributed.sharding import (ShardingRecipe, cache_specs, make_recipe,
                                     param_specs, use_recipe)
 from ..models import build, input_specs, param_shapes
 from ..optim import make_optimizer
-from ..roofline.analysis import collective_bytes_from_hlo, roofline_terms
-from .mesh import make_mini_mesh, make_production_mesh, set_mesh_compat
+from ..roofline.analysis import (DRYRUN_DEVICE_KIND,
+                                 collective_bytes_from_hlo, roofline_terms)
+from .mesh import make_mini_mesh, make_production_mesh
 from .steps import make_serve_step, make_train_step
 
 DEFAULT_OUT = "experiments/dryrun"
@@ -236,7 +239,8 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False,
             "multi_pod": multi_pod, "variant": variant,
             "params_total": cfg.param_count(),
             "params_active": cfg.active_param_count(),
-            "model_flops": model_flops_estimate(cfg, spec)}
+            "model_flops": model_flops_estimate(cfg, spec),
+            "device_kind": DRYRUN_DEVICE_KIND}
     ok, reason = shape_supported(cfg, shape)
     if not ok:
         return {"meta": meta, "status": "skipped", "reason": reason}
@@ -251,7 +255,7 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False,
     api = build(cfg)
     record: Dict = {"meta": meta, "status": "ok"}
     t0 = time.perf_counter()
-    with set_mesh_compat(mesh), use_recipe(recipe):
+    with jax.set_mesh(mesh), use_recipe(recipe):
         params_sds = param_shapes(cfg, spec)
         pspecs = param_specs(params_sds, recipe)
         params_in = _shard_sds(params_sds, pspecs, mesh)
@@ -332,8 +336,6 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False,
         record["memory_analysis"] = {"error": str(e)}
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):     # jax 0.4.x: [dict]
-            cost = cost[0] if cost else {}
         record["cost_analysis"] = {
             k: float(v) for k, v in cost.items()
             if k in ("flops", "transcendentals", "bytes accessed")
@@ -350,7 +352,8 @@ def lower_cell(arch: str, shape: str, multi_pod: bool = False,
     record["roofline"] = roofline_terms(
         float(cost.get("flops", 0.0)),
         float(cost.get("bytes accessed", 0.0)),
-        float(record["collectives"].get("weighted_total", 0.0)))
+        float(record["collectives"].get("weighted_total", 0.0)),
+        meta["device_kind"])
     # cost_analysis is the per-device SPMD program -> compare against the
     # per-device share of MODEL_FLOPS = 6·N·D (or 2·N·D for inference).
     record["roofline"]["useful_flops_ratio"] = (

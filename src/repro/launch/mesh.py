@@ -2,48 +2,24 @@
 
 A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (smoke tests must keep seeing 1 CPU device).
-
-Version compat: ``jax.sharding.AxisType`` and ``jax.make_mesh``'s
-``axis_types=`` kwarg only exist on newer jax; on 0.4.x we fall back to a
-plain mesh (all axes behave as the old default, which is what Auto means).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if _AXIS_TYPE is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-        except TypeError:        # jax with AxisType but older make_mesh
-            pass
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
-def set_mesh_compat(mesh):
-    """``jax.set_mesh`` context where available; on older jax the Mesh
-    object itself is the context manager that activates it."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        ctx = set_mesh(mesh)
-        # jax.set_mesh is a context manager in recent releases; guard in
-        # case a version makes it a plain setter returning None.
-        return ctx if hasattr(ctx, "__enter__") else contextlib.nullcontext()
-    return mesh
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_mini_mesh(*, multi_pod: bool = False, devices_per_axis: int = 2):
@@ -51,24 +27,35 @@ def make_mini_mesh(*, multi_pod: bool = False, devices_per_axis: int = 2):
     d = devices_per_axis
     shape = (2, d, d) if multi_pod else (d, d)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 # ------------------------------------------------------------ serving mesh --
-def shard_devices(num_shards: int):
-    """Device assignment for a sharded page pool: shard i's slab lives on
-    local device i.  With fewer devices than shards (one CPU, mini TPU
-    slices) devices are reused round-robin — the placement/routing logic
+def _local_devices_for(num_shards: int):
+    """The local devices, checked against ``num_shards``: on an
+    accelerator every shard needs a chip of its own, so asking for more
+    shards than chips raises instead of co-locating slabs.  On the CPU
+    (tests) devices are reused round-robin — the placement/routing logic
     is identical, only the physical spread shrinks."""
     local = jax.local_devices()
+    if num_shards > len(local) and local[0].platform != "cpu":
+        raise ValueError(
+            f"{num_shards} shards need {num_shards} devices; this host "
+            f"has {len(local)} {local[0].platform} device(s)")
+    return local
+
+
+def shard_devices(num_shards: int):
+    """Device assignment for a sharded page pool: shard i's slab lives on
+    local device i (round-robin reuse only on the CPU)."""
+    local = _local_devices_for(int(num_shards))
     return [local[i % len(local)] for i in range(int(num_shards))]
 
 
 def make_shard_mesh(num_shards: int):
-    """1-D ``("shard",)`` mesh for sharded page-pool serving.  The axis
-    is clamped to the local device count (a 4-shard pool on one CPU is a
-    1-device mesh with all four slabs co-located); the per-shard
-    DevicePagePools still pin to :func:`shard_devices`, so on a real
-    slice each shard's slab lands on its own chip."""
-    n = min(int(num_shards), len(jax.local_devices()))
-    return make_mesh_compat((max(1, n),), ("shard",))
+    """1-D ``("shard",)`` mesh for sharded page-pool serving.  On the CPU
+    the axis is clamped to the local device count (a 4-shard pool on one
+    CPU is a 1-device mesh with all four slabs co-located); on an
+    accelerator there is one chip per shard or a ValueError."""
+    n = min(int(num_shards), len(_local_devices_for(int(num_shards))))
+    return make_auto_mesh((max(1, n),), ("shard",))

@@ -541,9 +541,10 @@ def main(argv=None):
                     choices=sorted(SCHEDULERS))
     ap.add_argument("--backend", default="numpy",
                     choices=("numpy", "device"),
-                    help="numpy: host materialization (policy simulator); "
+                    help="numpy: host materialization, a policy "
+                         "simulator that never touches the device; "
                          "device: serve through the HBM page slab via the "
-                         "Pallas dedup kernels (DESIGN.md §3)")
+                         "Pallas dedup kernels on a TPU (DESIGN.md §3)")
     ap.add_argument("--transfer", default="grouped",
                     choices=("per_page", "grouped"),
                     help="host->HBM page movement: per_page (one "
@@ -596,6 +597,8 @@ def main(argv=None):
         raise SystemExit("--kill-after requires --snapshot (stopping "
                          "mid-run without a snapshot just loses work)")
 
+    from .cache import enable_compile_cache
+    enable_compile_cache()
     if args.engine == "lm":
         return serve_lm(args)
     return serve_embedding(args)

@@ -1,5 +1,5 @@
-from .analysis import (HW, collective_bytes_from_hlo, roofline_terms,
-                       summarize_cell)
+from .analysis import (DRYRUN_DEVICE_KIND, PEAKS, collective_bytes_from_hlo,
+                       peaks, roofline_terms, summarize_cell)
 
-__all__ = ["HW", "collective_bytes_from_hlo", "roofline_terms",
-           "summarize_cell"]
+__all__ = ["DRYRUN_DEVICE_KIND", "PEAKS", "collective_bytes_from_hlo",
+           "peaks", "roofline_terms", "summarize_cell"]

@@ -1,7 +1,9 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Hardware model (TPU v5e-class, per assignment):
-  197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+Peaks are per chip and keyed by ``device_kind`` as JAX reports it
+(:data:`PEAKS`); a kind missing from the table is an error, never a
+default.  The dry-run compiles for a pod of TPU v5e chips
+(:data:`DRYRUN_DEVICE_KIND`).
 
 The compiled module is the *per-device* SPMD program, so
 ``cost_analysis()`` FLOPs/bytes and parsed collective bytes are already
@@ -22,11 +24,31 @@ from __future__ import annotations
 import re
 from typing import Dict, Optional
 
-HW = {
-    "peak_flops": 197e12,        # bf16 per chip
-    "hbm_bw": 819e9,             # bytes/s
-    "ici_bw": 50e9,              # bytes/s per link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+    # HBM at 819 GB/s, 1,600 Gbit/s of interchip interconnect per chip
+    # (~50 GB/s per link of four).
+    "TPU v5 lite": {
+        "peak_flops": 197e12,        # bf16
+        "hbm_bw": 819e9,             # bytes/s
+        "ici_bw": 50e9,              # bytes/s per link
+        "source": "Google Cloud documentation, TPU v5e",
+    },
 }
+
+#: the chip the dry-run's production mesh is made of
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The :data:`PEAKS` row of ``device_kind``; raises for unknown kinds."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,
@@ -85,11 +107,13 @@ def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, float]:
 
 
 def roofline_terms(flops: float, bytes_accessed: float,
-                   collective_bytes: float) -> Dict[str, float]:
+                   collective_bytes: float,
+                   device_kind: str) -> Dict[str, float]:
+    hw = peaks(device_kind)
     terms = {
-        "compute_s": flops / HW["peak_flops"],
-        "memory_s": bytes_accessed / HW["hbm_bw"],
-        "collective_s": collective_bytes / HW["ici_bw"],
+        "compute_s": flops / hw["peak_flops"],
+        "memory_s": bytes_accessed / hw["hbm_bw"],
+        "collective_s": collective_bytes / hw["ici_bw"],
     }
     dominant = max(terms, key=terms.get)
     terms["dominant"] = dominant
@@ -105,7 +129,8 @@ def summarize_cell(record: Dict, model_flops: Optional[float] = None) -> Dict:
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     coll = record.get("collectives", {})
     terms = roofline_terms(flops, bytes_accessed,
-                           float(coll.get("weighted_total", 0.0)))
+                           float(coll.get("weighted_total", 0.0)),
+                           record["meta"]["device_kind"])
     out = dict(record.get("meta", {}))
     out.update(terms)
     out["flops"] = flops

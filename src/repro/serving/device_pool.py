@@ -4,18 +4,21 @@ The paper pages deduplicated blocks between disk and DRAM; on TPU the
 same two tiers are host DRAM (the ModelStore's distinct-block arrays)
 and HBM (DESIGN.md §2).  :class:`DevicePagePool` is the HBM side:
 
-  * a **fixed preallocated slab** ``[capacity_pages, blocks_per_page,
-    bh, bw]`` living on the accelerator — page loads are real
-    ``jax.device_put`` + ``dynamic_update_slice`` transfers, not numpy
-    copies;
+  * a **fixed preallocated slab** ``[capacity_pages, page_rows, 128]``
+    living on the accelerator: each page's ``blocks_per_page`` blocks
+    laid end to end as 128-lane rows (see ``kernels/dedup_embedding``).
+    A ``[..., 64, 64]`` float32 slab would be padded to 128 lanes on
+    the TPU (twice its bytes) and could not be copied by DMA row by row;
+    lane rows are dense.  Page loads are real ``jax.device_put`` +
+    scatter transfers, not numpy copies;
   * a **physical→slot remap**: :meth:`remap` rewrites a
     ``ModelStore.virtual_tensor`` flat block map (physical slot space,
     ``page * l + slot``) into slab-slot space (``slab_slot * l + slot``)
     with one vectorized lookup, cached per (packing, slab) generation;
   * **compute entry points** — :meth:`gather_rows`, :meth:`virtual_matmul`,
     :meth:`unblock` — that run the Pallas dedup kernels (or their jitted
-    XLA equivalents off-TPU) directly against the resident slab, so
-    inference never densifies weights on the host.
+    XLA equivalents) directly against the resident slab, so inference
+    never densifies weights on the host.
 
 The pool is driven by :class:`~repro.core.bufferpool.BufferPool` through
 its ``on_load``/``on_evict`` callbacks: the policy simulator stays the
@@ -24,7 +27,7 @@ keeps the invariant ``slab occupied slots == pool resident set``.
 
 Kernel mode — how :meth:`gather_rows` / :meth:`virtual_matmul` execute:
 
-  * ``"pallas"``: the Pallas dedup kernels (interpret-mode off-TPU —
+  * ``"pallas"``: the Pallas dedup kernels (interpret mode on the CPU —
     the correctness path the equivalence tests exercise).
   * ``"xla"``: jitted XLA gathers, the same math lowered without Pallas
     (the right choice on GPU).
@@ -34,7 +37,8 @@ Kernel mode — how :meth:`gather_rows` / :meth:`virtual_matmul` execute:
     fast path there: same slot remap, same residency invariant, zero
     per-batch weight densification; interpret-mode Pallas and eager XLA
     gathers are correctness tools, not performance paths, on CPU.
-  * ``"auto"`` (default): Pallas on TPU, host mirror otherwise.
+  * ``"auto"`` (default): Pallas on TPU, the host mirror on CPU, XLA on
+    any other accelerator.
 """
 from __future__ import annotations
 
@@ -49,6 +53,7 @@ import numpy as np
 from ..core.blocks import BlockGrid
 from ..core.store import ModelStore, VirtualTensor
 from ..kernels import ops
+from ..kernels.dedup_embedding import LANES, lane_geometry
 from ..obs import get_tracer
 from .transfer import TransferEngine
 
@@ -56,16 +61,18 @@ __all__ = ["DevicePagePool"]
 
 
 # --------------------------------------------------------- jitted XLA paths --
-@functools.partial(jax.jit, static_argnames=("bh", "width"))
-def _gather_rows_xla(slab, bmap2d, rows, *, bh: int, width: int):
-    """Row gather without densifying: the slab is viewed as a flat stack
-    of block *rows* ([S*l*bh, bw]) and exactly the requested rows are
-    gathered — the XLA lowering of what dedup_embedding does via DMA."""
-    S, l, _, bw = slab.shape
-    flat_rows = slab.reshape(S * l * bh, bw)
+@functools.partial(jax.jit, static_argnames=("block_shape", "width"))
+def _gather_rows_xla(slab, bmap2d, rows, *, block_shape, width: int):
+    """Row gather without densifying: exactly the requested block rows
+    are gathered from the slab's lane-row view — the XLA lowering of
+    what dedup_embedding does by DMA."""
+    bh, bw = block_shape
+    rows_per_block = lane_geometry(block_shape)[0]
+    lanes = slab.reshape(-1, LANES)
     rb, off = rows // bh, rows % bh
-    dev = bmap2d[rb]                                  # [n, gw]
-    out = flat_rows[dev * bh + off[:, None]]          # [n, gw, bw]
+    start = bmap2d[rb] * rows_per_block + (off * bw // LANES)[:, None]
+    col = (off * bw % LANES)[:, None, None] + jnp.arange(bw)   # [n, 1, bw]
+    out = lanes[start[:, :, None] + col // LANES, col % LANES]  # [n, gw, bw]
     return out.reshape(out.shape[0], -1)[:, :width]
 
 
@@ -73,9 +80,11 @@ def _gather_rows_xla(slab, bmap2d, rows, *, bh: int, width: int):
 def _unblock_xla(slab, dev_map, *, grid: BlockGrid):
     """Reassemble a full tensor from resident slab blocks on device
     (the LM-serving load path: zero host-side materialization)."""
-    S, l, bh, bw = slab.shape
+    bh, bw = grid.block_shape
+    rows_per_block = lane_geometry((bh, bw))[0]
     gh, gw = grid.grid
-    blocks = jnp.take(slab.reshape(S * l, bh, bw), dev_map, axis=0)
+    blocks = jnp.take(slab.reshape(-1, rows_per_block, LANES), dev_map,
+                      axis=0)
     x2 = (blocks.reshape(gh, gw, bh, bw)
                 .transpose(0, 2, 1, 3)
                 .reshape(gh * bh, gw * bw))
@@ -88,6 +97,16 @@ def _matmul_xla(slab, bmap2d, x, *, grid: BlockGrid):
     W = W.reshape(grid.shape2d)
     return jnp.matmul(x[..., :grid.shape2d[0]], W,
                       preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def pallas_matmul_accepts(block_shape) -> bool:
+    """Block shapes pallas-mode :meth:`DevicePagePool.virtual_matmul`
+    compiles for on the TPU (DESIGN.md §3): ``bw == 128`` so the
+    lane-row slab reads as ``[n, bh, 128]`` blocks without a copy, and
+    ``bh`` a multiple of 128 so the kernel's x tile ``(bm, bh)`` is
+    lane-aligned."""
+    bh, bw = block_shape
+    return bw == LANES and bh % LANES == 0
 
 
 def _pad_pow2(n: int, floor: int = 8) -> int:
@@ -109,6 +128,9 @@ class DevicePagePool:
         bh, bw = store.cfg.dedup.block_shape
         self.block_shape = (bh, bw)
         self.blocks_per_page = store.cfg.blocks_per_page
+        # lane rows per page in the slab's [rows, page_rows, 128] layout
+        self.page_rows = self.blocks_per_page * lane_geometry(
+            self.block_shape)[0]
         self.capacity = int(capacity_pages)
         # Borrow-staging tail (sharded serving): ``stage_rows`` extra
         # page rows allocated PAST the resident slots, written by
@@ -128,7 +150,7 @@ class DevicePagePool:
         # below is the tier's physical backing, so the device buffer is
         # never allocated at all.
         self.slab = None if self.mode() == "host" else self._put(jnp.zeros(
-            (rows, self.blocks_per_page, bh, bw), dtype))
+            (rows, self.page_rows, LANES), dtype))
         # Host mirror, kept page-for-page identical with the slab: the
         # "host" kernel mode computes from it, and off-accelerator it is
         # the physical backing of the tier anyway.
@@ -156,6 +178,11 @@ class DevicePagePool:
     def _put(self, x):
         """Commit an array to this pool's device (identity when unpinned)."""
         return x if self.device is None else jax.device_put(x, self.device)
+
+    def to_lanes(self, pages: np.ndarray) -> np.ndarray:
+        """Host pages ``[k, l, bh, bw]`` -> the slab's ``[k, page_rows,
+        128]`` layout (a free reshape of contiguous float32 pages)."""
+        return pages.reshape(len(pages), self.page_rows, LANES)
 
     # ------------------------------------------------------ page movement --
     def load(self, pid: int) -> None:
@@ -185,8 +212,9 @@ class DevicePagePool:
             if self.mode() != "host":
                 self.slab = jax.lax.dynamic_update_slice(
                     self.slab,
-                    self._put(jnp.asarray(page[None], self.dtype)),
-                    (slot, 0, 0, 0))
+                    self._put(jnp.asarray(self.to_lanes(page[None]),
+                                          self.dtype)),
+                    (slot, 0, 0))
             self.host_slab[slot] = page
             self.slot_of[pid] = slot
             self._page_to_slot[pid] = slot
@@ -232,24 +260,25 @@ class DevicePagePool:
     def occupied_slots(self) -> Set[int]:
         return set(self.slot_of.values())
 
-    def flat_pool(self) -> jnp.ndarray:
-        """Kernel view of the slab (incl. any staging tail):
-        [(capacity+stage_rows)*blocks_per_page, bh, bw]."""
-        bh, bw = self.block_shape
-        return self.slab.reshape(self.slab.shape[0] * self.blocks_per_page,
-                                 bh, bw)
-
     def slot_page(self, slot: int) -> np.ndarray:
-        """Host copy of one slab slot (tests / debugging)."""
+        """Host copy of one slab slot as ``[l, bh, bw]`` (tests /
+        debugging)."""
         if self.mode() == "host":
             return self.host_slab[slot].copy()
-        return np.asarray(self.slab[slot])
+        return np.asarray(self.slab[slot]).reshape(self.host_slab.shape[1:])
+
+    def platform(self) -> str:
+        """Platform of the device this pool's slab lives on."""
+        return self.device.platform if self.device is not None \
+            else jax.default_backend()
 
     def mode(self) -> str:
-        """Resolved compute mode: pallas | xla | host."""
+        """Resolved compute mode: pallas | xla | host.  ``auto`` is the
+        host mirror only on the CPU; on the TPU it is the Pallas kernels,
+        on any other accelerator the XLA gathers."""
         if self.kernel_mode != "auto":
             return self.kernel_mode
-        return "pallas" if jax.default_backend() == "tpu" else "host"
+        return {"tpu": "pallas", "cpu": "host"}.get(self.platform(), "xla")
 
     def use_pallas(self) -> bool:
         return self.mode() == "pallas"
@@ -336,22 +365,24 @@ class DevicePagePool:
         with get_tracer().span("kernel", kind="kernel", op="gather_rows",
                                mode=mode, rows=n):
             if mode == "pallas":
-                pool = self.slab.reshape(self.slab.shape[0] * l, bh, bw)
                 out = ops.dedup_embedding_striped(
-                    self._put(jnp.asarray(ids)), pool,
-                    self._put(jnp.asarray(bmap2d)), width=width)
+                    self._put(jnp.asarray(ids)), self.slab,
+                    self._put(jnp.asarray(bmap2d)), self.block_shape,
+                    width=width)
             else:
                 out = _gather_rows_xla(self.slab,
                                        self._put(jnp.asarray(bmap2d)),
                                        self._put(jnp.asarray(ids)),
-                                       bh=bh, width=width)
+                                       block_shape=self.block_shape,
+                                       width=width)
         return out if pad else out[:n]
 
     def virtual_matmul(self, dev_map: np.ndarray, grid: BlockGrid, x):
         """``x @ W_virtual`` with W never densified: dedup_matmul streams
         slab blocks through the scalar-prefetched block map (pallas);
         host mode runs the same k-loop blockwise in numpy against the
-        slab mirror."""
+        slab mirror.  Pallas mode on the TPU raises for block shapes
+        :func:`pallas_matmul_accepts` refuses; it never falls back."""
         bh, bw = self.block_shape
         gh, gw = grid.grid
         K, N = grid.shape2d
@@ -378,12 +409,19 @@ class DevicePagePool:
         with get_tracer().span("kernel", kind="kernel",
                                op="virtual_matmul", mode=mode):
             if mode == "pallas":
+                if not ops._interpret() \
+                        and not pallas_matmul_accepts(self.block_shape):
+                    raise ValueError(
+                        f"pallas virtual_matmul does not compile for "
+                        f"{self.block_shape} blocks on the TPU; it needs "
+                        f"bw == {LANES} and bh a multiple of {LANES} "
+                        f"(DESIGN.md §3)")
                 pad = gh * bh - x.shape[-1]
                 if pad:
                     widths = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
                     x = jnp.pad(x, widths)
-                bm = 128 if jax.default_backend() == "tpu" else 8
-                pool = self.slab.reshape(self.slab.shape[0] * l, bh, bw)
+                bm = 8 if ops._interpret() else 128
+                pool = self.slab.reshape(-1, bh, bw)
                 y = ops.dedup_matmul(self._put(x), pool,
                                      self._put(jnp.asarray(bmap2d)), bm=bm)
                 return y[..., :N]

@@ -484,8 +484,9 @@ class ShardedPagePool:
             import jax
             import jax.numpy as jnp
             pool.slab = jax.lax.dynamic_update_slice(
-                pool.slab, pool._put(jnp.asarray(buf, pool.dtype)),
-                (pool.capacity, 0, 0, 0))
+                pool.slab,
+                pool._put(jnp.asarray(pool.to_lanes(buf), pool.dtype)),
+                (pool.capacity, 0, 0))
         self._stage_dirty[shard] = False
 
     def _unpin(self, shard: int, out):
@@ -554,8 +555,8 @@ class ShardedPagePool:
         # stage through the host: the per-shard slabs are committed to
         # different devices, so stacking them directly would mix devices
         # (the transient borrow-staging tails are not part of the pool)
-        stacked = np.stack([np.asarray(p.slab)[:p.capacity]
-                            for p in self.pools])
+        stacked = np.stack([np.asarray(p.slab)[:p.capacity].reshape(
+            (p.capacity,) + p.host_slab.shape[1:]) for p in self.pools])
         if mesh is None:
             return jnp.asarray(stacked)
         from ..distributed.sharding import slab_sharding

@@ -97,7 +97,8 @@ class PendingGroup:
     device copy already issued (async) when the pool has a device slab."""
     index: Dict[int, int]            # pid -> row in the stack
     host: np.ndarray                 # [k, l, bh, bw] staging buffer
-    dev: Optional[object]            # device copy (None in host mode)
+    dev: Optional[object]            # [k, page_rows, 128] device copy
+                                     # (None in host mode)
     pack_generation: int
 
 
@@ -158,8 +159,11 @@ class TransferEngine:
         return self.pool.store.page_stack(pids, dtype=np.float32)
 
     def _to_device(self, stack: np.ndarray):
+        """Ship a host page stack ``[k, l, bh, bw]`` in the slab's
+        lane-row layout ``[k, page_rows, 128]``."""
         import jax.numpy as jnp
-        return self.pool._put(jnp.asarray(stack, self.pool.dtype))
+        return self.pool._put(jnp.asarray(self.pool.to_lanes(stack),
+                                          self.pool.dtype))
 
     def _scatter(self, slab, slots: np.ndarray, staged):
         """One scatter committing ``staged`` rows into ``slots``, padded

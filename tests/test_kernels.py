@@ -43,15 +43,52 @@ def test_dedup_matmul_batched_lead_dims():
     assert y.shape == (2, 5, 32)
 
 
-@pytest.mark.parametrize("V,bv,D,B", [(64, 8, 32, 7), (128, 16, 64, 33)])
+@pytest.mark.parametrize("V,bv,D,B", [
+    (64, 8, 32, 7), (128, 16, 64, 33),
+    (256, 64, 64, 8),               # one full row tile, 2 rows per lane row
+    (256, 64, 64, 13),              # ragged last row tile
+    (64, 8, 256, 9),                # a row spans two lane rows
+])
 def test_dedup_embedding_sweep(V, bv, D, B):
     pool = RNG.standard_normal((5, bv, D)).astype(np.float32)
     rbmap = RNG.integers(0, 5, (V // bv,)).astype(np.int32)
     ids = RNG.integers(0, V, (B,)).astype(np.int32)
     e = ops.dedup_embedding(jnp.asarray(ids), jnp.asarray(pool),
                             jnp.asarray(rbmap))
-    expect = np.stack([pool[rbmap[i // bv]][i % bv] for i in ids])
-    np.testing.assert_allclose(np.asarray(e), expect, rtol=1e-6)
+    expect = ref.dedup_embedding(jnp.asarray(ids), jnp.asarray(pool),
+                                 jnp.asarray(rbmap), D)
+    np.testing.assert_array_equal(np.asarray(e), np.asarray(expect))
+
+
+@pytest.mark.parametrize("bh,bw,gh,gw,B", [
+    (64, 64, 3, 4, 16),             # the store's blocks, 2 row tiles
+    (64, 64, 2, 3, 11),             # ragged last row tile
+    (16, 16, 4, 2, 5),              # 8 block rows per lane row
+    (16, 128, 2, 3, 9),             # one block row per lane row
+])
+@pytest.mark.parametrize("lane_rows", [False, True])
+def test_dedup_embedding_striped_sweep(bh, bw, gh, gw, B, lane_rows):
+    """Column-striped gather == rows of the reference's materialized
+    virtual tensor, from a block pool or its lane-row (slab) layout."""
+    pool = RNG.standard_normal((6, bh, bw)).astype(np.float32)
+    bmap = RNG.integers(0, 6, (gh, gw)).astype(np.int32)
+    ids = RNG.integers(0, gh * bh, (B,)).astype(np.int32)
+    src = pool.reshape(-1, 128) if lane_rows else pool
+    width = gw * bw - 5
+    e = ops.dedup_embedding_striped(jnp.asarray(ids), jnp.asarray(src),
+                                    jnp.asarray(bmap), (bh, bw),
+                                    width=width)
+    W = ref.materialize_virtual(jnp.asarray(pool), jnp.asarray(bmap),
+                                gh * bh, gw * bw)
+    np.testing.assert_array_equal(np.asarray(e),
+                                  np.asarray(W)[ids][:, :width])
+
+
+def test_dedup_embedding_refuses_blocks_off_the_lane_grid():
+    pool = jnp.zeros((2, 4, 8), jnp.float32)          # 32 floats per block
+    with pytest.raises(ValueError, match="lane"):
+        ops.dedup_embedding_striped(jnp.zeros((8,), jnp.int32), pool,
+                                    jnp.zeros((1, 1), jnp.int32), (4, 8))
 
 
 @pytest.mark.parametrize("n,dim,nh,r", [
