@@ -93,6 +93,7 @@ class BufferPool:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.victim_scanned = 0      # pages _pick_victim examined
         self.prefetches = 0          # pages admitted by prefetch()
         self.prefetch_declined = 0   # prefetch offers the policy refused
         self._lambda: Dict[ModelId, float] = defaultdict(float)
@@ -109,7 +110,16 @@ class BufferPool:
 
     def reset_stats(self) -> None:
         self.hits = self.misses = self.evictions = 0
+        self.victim_scanned = 0
         self.prefetches = self.prefetch_declined = 0
+
+    def register_into(self, registry, namespace: str = "pool") -> None:
+        """Register the demand counters (``hits``, ``misses``,
+        ``evictions``, ``victim_scanned``) as live counter views in a
+        :class:`~repro.obs.metrics.MetricsRegistry`."""
+        for f in ("hits", "misses", "evictions", "victim_scanned"):
+            registry.counter(f"{namespace}.{f}",
+                             lambda o=self, a=f: getattr(o, a))
 
     def model_rates(self) -> Dict[ModelId, float]:
         """Per-model arrival-rate estimates (the lambda_i of Eq. 2), as
@@ -241,12 +251,14 @@ class BufferPool:
                 f"group of {len(set(pages))} pages exceeds pool capacity "
                 f"{self.cfg.capacity_pages}")
         self._pinned = set(pages)
+        tr = get_tracer()
         try:
-            with get_tracer().span("pool_group", kind="pool", model=model,
-                                   pages=len(pages)) as sp:
+            with tr.span("pool_group", kind="pool", model=model,
+                         pages=len(pages)) as sp:
                 with self.deferred_loads():
                     hits = [self.access(model, p) for p in pages]
-                sp.set(hits=sum(hits))
+                if tr.enabled:
+                    sp.set(hits=sum(hits))
                 return hits
         finally:
             self._pinned = set()
@@ -287,11 +299,16 @@ class BufferPool:
 
     def _victim_in_set(self, pages, inner: str) -> PageId:
         # Recency order within the set, using resident OrderedDict order.
+        self.victim_scanned += len(self.resident)
         ordered = [p for p in self.resident if p in pages]
         return ordered[-1] if inner == "mru" else ordered[0]
 
     def _pick_victim(self) -> PageId:
+        """The policy's victim.  ``victim_scanned`` counts every page
+        each pass here looks at: the resident set, the evictable list,
+        and the resident set again for each locality set's candidate."""
         pol = self.cfg.policy
+        self.victim_scanned += len(self.resident)
         evictable = [p for p in self.resident if p not in self._pinned]
         if not evictable:
             raise RuntimeError("every resident page is pinned; "
@@ -301,10 +318,12 @@ class BufferPool:
         if pol == "mru":
             return evictable[-1]
         if pol == "lfu":
+            self.victim_scanned += len(evictable)
             return min(evictable, key=lambda p: (self.meta[p].freq,
                                                  self.meta[p].last_tick))
         inner = "mru" if pol.endswith("mru") else "lru"
         by_set: Dict[Hashable, Set[PageId]] = defaultdict(set)
+        self.victim_scanned += len(evictable)
         for p in evictable:
             by_set[self.meta[p].locality_set].add(p)
         best, best_cost = None, None
@@ -320,11 +339,18 @@ class BufferPool:
         return best
 
     def _evict_one(self) -> None:
-        victim = self._pick_victim()
-        del self.resident[victim]
-        self.evictions += 1
-        if self.on_evict:
-            self.on_evict(victim)
+        tr = get_tracer()
+        with tr.span("evict", kind="pool") as sp:
+            victim = self._pick_victim()
+            del self.resident[victim]
+            self.evictions += 1
+            if self.on_evict:
+                self.on_evict(victim)
+            if tr.enabled:
+                # the counters as they stand, so a trace reads their
+                # change between any two evictions
+                sp.set(victim_scanned=self.victim_scanned,
+                       evictions=self.evictions)
 
     # ----------------------------------------------------------- prefetch --
     def prefetch(self, model: ModelId, page: PageId) -> bool:

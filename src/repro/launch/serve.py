@@ -259,13 +259,17 @@ def _build_registry(stats: ServeStats, server, engine, clock):
     """One MetricsRegistry over every live stats surface of this run:
     engine counters (``serve.``), the server's access-path counters
     (``server.`` — a distinct ServeStats when the engine wraps a
-    WeightServer), recovery, prefetch, and the virtual clock."""
+    WeightServer), the buffer pool's counters (``pool.``), recovery,
+    prefetch, and the virtual clock."""
     from ..obs import MetricsRegistry
     reg = MetricsRegistry()
     stats.register_into(reg, namespace="serve")
     srv_stats = getattr(server, "stats", None)
     if srv_stats is not None and srv_stats is not stats:
         srv_stats.register_into(reg, namespace="server")
+    pool = getattr(server, "pool", None)
+    if pool is not None:
+        pool.register_into(reg, "pool")
     fault_stats = getattr(getattr(server, "store", None),
                           "fault_stats", None)
     if fault_stats is not None:

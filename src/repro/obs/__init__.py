@@ -1,11 +1,13 @@
 """Observability for the dedup serving stack (DESIGN.md §10).
 
-Three pieces, all on the virtual clock:
+Three pieces:
 
   * :mod:`repro.obs.trace` — nested spans with named-channel charge
-    accounting.  The default tracer is a zero-allocation no-op, so the
-    serving hot path pays one ``get_tracer()`` attribute hop when
-    tracing is off.
+    accounting on the virtual clock, or on the host's clock
+    (:class:`HostClock`), where each span is also a ``repro.<name>``
+    annotation in the ``jax.profiler`` trace.  The default tracer is a
+    zero-allocation no-op, so the serving hot path pays one
+    ``get_tracer()`` attribute hop when tracing is off.
   * :mod:`repro.obs.metrics` — one enumerable :class:`MetricsRegistry`
     over the stats dataclasses (``ServeStats``, ``RecoveryStats``,
     pool / transfer / router counters) that were previously N
@@ -21,6 +23,7 @@ clock discipline.
 """
 from .trace import (
     NULL_TRACER,
+    HostClock,
     NullTracer,
     Span,
     Tracer,
@@ -38,6 +41,7 @@ from .export import (
 
 __all__ = [
     "NULL_TRACER",
+    "HostClock",
     "NullTracer",
     "Span",
     "Tracer",

@@ -1,8 +1,14 @@
-"""Virtual-clock request-path tracing.
+"""Request-path tracing on the virtual clock or the host's.
 
 A :class:`Tracer` produces nested spans ``(name, kind, start_t,
 end_t, channel, attrs)`` timed on a
-:class:`~repro.serving.traffic.VirtualClock`.  Three usage shapes:
+:class:`~repro.serving.traffic.VirtualClock` or, with
+``Tracer(clock=HostClock())``, on the host's ``time.perf_counter()``.
+A host-clock tracer also opens a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` for every span, carrying its scalar attrs, so
+under the profiler the program's spans share a clock with the
+device's operations.  A span opened inside one that carries a ``seq``
+attr (a batch's arrival number) inherits it.  Three usage shapes:
 
   * ``with tracer.span("fetch", kind="frontend", channel="ssd",
     charge=dt): clock.advance(dt, "ssd")`` — a *charged* span: the
@@ -17,7 +23,8 @@ end_t, channel, attrs)`` timed on a
     — an *attributed* span (no charge): pure structure + attrs, used
     by engines / pools / backends whose virtual seconds are folded
     onto the clock later by the frontend.  ``sp.set(bytes=...)`` adds
-    attrs discovered mid-flight.
+    attrs discovered mid-flight.  Charged spans exist only on a virtual
+    clock: a host-clock tracer rejects ``charge=``.
   * ``tracer.emit("request", arrival, done, kind="request", rid=...)``
     — a retrospective span for intervals that cannot be live context
     managers because they interleave (one span per request id,
@@ -26,7 +33,9 @@ end_t, channel, attrs)`` timed on a
 The default tracer is :data:`NULL_TRACER`, a no-op that allocates
 nothing per call (one shared null context manager, one shared null
 span), so instrumentation left in the hot path is free when tracing is
-off.  Spans may only be opened via the context manager — the
+off.  While a ``jax.profiler`` trace records and no tracer is
+installed, :func:`get_tracer` hands each thread a host-clock tracer
+instead, so a profile of the program carries its spans unasked.  Spans may only be opened via the context manager — the
 ``span-discipline`` lint bans bare :meth:`Tracer.span_begin` /
 :meth:`Tracer.span_end` pairs outside this module.
 
@@ -38,10 +47,15 @@ long run stays bounded without corrupting open trees or conservation.
 from __future__ import annotations
 
 import contextlib
+import numbers
+import sys
+import threading
+import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional
 
 __all__ = [
+    "HostClock",
     "Span",
     "Tracer",
     "NullTracer",
@@ -52,14 +66,47 @@ __all__ = [
 ]
 
 
+def _scalars(attrs: dict) -> dict:
+    """The attrs a profiler annotation can carry: numbers and strings."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (numbers.Number, str))}
+
+
+class HostClock:
+    """The host's clock for a :class:`Tracer`: ``now`` is seconds of
+    ``time.perf_counter()``.  :meth:`annotate` opens the profiler
+    annotation a host-clock tracer puts around each span; jax is
+    imported on first use, so modules that trace import no jax."""
+
+    PREFIX = "repro."
+
+    def __init__(self):
+        self._annotation = None
+
+    @property
+    def now(self) -> float:
+        return time.perf_counter()
+
+    def annotate(self, name: str, attrs: dict):
+        """An entered ``TraceAnnotation("repro." + name, **scalars)``."""
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        ann = self._annotation(self.PREFIX + name, **_scalars(attrs))
+        ann.__enter__()
+        return ann
+
+
 class Span:
     """One finished or in-flight span.  ``start_t`` / ``end_t`` are
-    virtual seconds (or monotonic event counts when the tracer has no
-    clock); ``charge`` is the float charged to ``channel`` on the
-    virtual clock, ``None`` for purely attributed spans."""
+    virtual seconds, host seconds on a :class:`HostClock`, or monotonic
+    event counts when the tracer has no clock; ``charge`` is the float
+    charged to ``channel`` on the virtual clock, ``None`` for purely
+    attributed spans; ``annotation`` is the open profiler annotation of
+    a host-clock span."""
 
     __slots__ = ("sid", "parent", "name", "kind", "start_t", "end_t",
-                 "channel", "charge", "attrs")
+                 "channel", "charge", "attrs", "annotation")
 
     def __init__(self, sid: int, parent: Optional[int], name: str,
                  kind: str, start_t: float,
@@ -75,10 +122,14 @@ class Span:
         self.channel = channel
         self.charge = charge
         self.attrs = attrs or {}
+        self.annotation = None
 
     def set(self, **attrs) -> "Span":
-        """Attach attrs discovered after the span opened."""
+        """Attach attrs discovered after the span opened (the open
+        profiler annotation gets the scalar ones too)."""
         self.attrs.update(attrs)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**_scalars(attrs))
         return self
 
     @property
@@ -174,11 +225,13 @@ NULL_TRACER = NullTracer()
 
 
 class Tracer:
-    """Span recorder bound to (at most) one virtual clock.
+    """Span recorder bound to (at most) one clock.
 
-    ``clock``: a :class:`~repro.serving.traffic.VirtualClock` used as
-    the time source; ``None`` falls back to a monotonic event counter
-    (ordering-only timestamps for clock-less unit tests).  ``ring``:
+    ``clock``: a :class:`~repro.serving.traffic.VirtualClock` or a
+    :class:`HostClock` used as the time source; ``None`` falls back to
+    a monotonic event counter (ordering-only timestamps for clock-less
+    unit tests).  A clock with an ``annotate`` method (the host clock)
+    has every span mirrored into the profiler's trace.  ``ring``:
     retention cap on *finished* spans — the deque drops oldest-first.
 
     One tracer is meant to witness one traced run against one fresh
@@ -190,6 +243,7 @@ class Tracer:
         if ring < 1:
             raise ValueError("ring must hold at least one span")
         self.clock = clock
+        self._annotate = getattr(clock, "annotate", None)
         self.enabled = True
         self.channel_seconds: Dict[str, float] = {}
         self._ring: "deque[Span]" = deque(maxlen=int(ring))
@@ -210,10 +264,15 @@ class Tracer:
     def span_begin(self, name: str, kind: str = "span",
                    channel: Optional[str] = None,
                    charge: Optional[float] = None, **attrs) -> Span:
-        parent = self._stack[-1].sid if self._stack else None
-        sp = Span(self._next_sid, parent, name, kind, self._now(),
-                  channel=channel, charge=charge, attrs=attrs)
+        top = self._stack[-1] if self._stack else None
+        if top is not None and "seq" in top.attrs and "seq" not in attrs:
+            attrs["seq"] = top.attrs["seq"]
+        sp = Span(self._next_sid, None if top is None else top.sid, name,
+                  kind, self._now(), channel=channel, charge=charge,
+                  attrs=attrs)
         self._next_sid += 1
+        if self._annotate is not None:
+            sp.annotation = self._annotate(name, attrs)
         self._stack.append(sp)
         return sp
 
@@ -224,6 +283,8 @@ class Tracer:
                 f"{[s.name for s in self._stack]})")
         self._stack.pop()
         sp.end_t = self._now()
+        if sp.annotation is not None:
+            sp.annotation.__exit__(None, None, None)
         if sp.channel is not None and sp.charge is not None:
             # the *identical* update VirtualClock.advance performs, fed
             # the identical float, in the same order -> exact equality
@@ -243,9 +304,13 @@ class Tracer:
              charge: Optional[float] = None, **attrs) -> _SpanHandle:
         """Open a nested span as a context manager.  Pass ``channel``
         and ``charge`` together to book virtual seconds (the same float
-        handed to ``clock.advance``); either alone is an error."""
+        handed to ``clock.advance``); either alone is an error, and so
+        is a charge on a host-clock tracer."""
         if (channel is None) != (charge is None):
             raise ValueError("channel and charge must be given together")
+        if charge is not None and self._annotate is not None:
+            raise ValueError("charged spans book virtual seconds; a "
+                             "host-clock tracer takes none")
         return _SpanHandle(self, self.span_begin(
             name, kind=kind, channel=channel, charge=charge, **attrs))
 
@@ -307,10 +372,39 @@ class Tracer:
 
 # ------------------------------------------------------ global tracer ----
 _ACTIVE: "NullTracer | Tracer" = NULL_TRACER
+_PROFILED = threading.local()      # .tracer: this thread's profiler tracer
+_profiler_on = None                # jax's TraceAnnotation.is_enabled
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` trace is recording.  No profiler runs
+    before jax is imported, so this imports none."""
+    global _profiler_on
+    if _profiler_on is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+        _profiler_on = TraceAnnotation.is_enabled
+    return _profiler_on()
+
+
+def _profiler_tracer() -> Tracer:
+    """This thread's host-clock tracer for profiled stretches.  Its
+    spans are read back from the profile, so it keeps only the newest."""
+    tr = getattr(_PROFILED, "tracer", None)
+    if tr is None:
+        tr = _PROFILED.tracer = Tracer(clock=HostClock(), ring=1)
+    return tr
 
 
 def get_tracer() -> "NullTracer | Tracer":
-    """The active tracer (the no-op :data:`NULL_TRACER` by default)."""
+    """The active tracer: the one installed with :func:`set_tracer`;
+    with none installed, while a ``jax.profiler`` trace records, a
+    host-clock tracer of the calling thread, so every profile holds the
+    program's ``repro.*`` spans with no set-up; else the no-op
+    :data:`NULL_TRACER`."""
+    if _ACTIVE is NULL_TRACER and _profiling():
+        return _profiler_tracer()
     return _ACTIVE
 
 

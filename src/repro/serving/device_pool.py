@@ -335,44 +335,54 @@ class DevicePagePool:
         caches stay warm across varying batch row counts; ``pad=True``
         returns the padded ``[bucket, width]`` array (rows past ``n`` are
         row-0 garbage) so *downstream* jits also see stable shapes —
-        indices into the first ``n`` rows are unaffected."""
+        indices into the first ``n`` rows are unaffected.
+
+        Spans: ``gather_prep`` holds the host work before the kernel
+        (the hole check, id padding, and the ``upload`` of ids and block
+        map); ``kernel`` holds the dispatch alone."""
         bh, bw = self.block_shape
         gh, gw = grid.grid
         width = grid.shape2d[1]
-        rows = np.asarray(rows)      # repro: allow-host (index array)
-        n = len(rows)
-        bmap2d = dev_map.reshape(gh, gw)
-        # Partial remaps carry -1 holes; negative indexing would silently
-        # wrap to the wrong slab bytes, so a touched hole (the caller's
-        # page set failed to cover its rows) must surface as None — the
-        # engines then take the host fallback instead of serving garbage.
-        if n and (bmap2d[np.unique(rows // bh)] < 0).any():
-            return None
-        mode = self.mode()
-        l = self.blocks_per_page
+        tr = get_tracer()
+        with tr.span("gather_prep", kind="kernel"):
+            rows = np.asarray(rows)      # repro: allow-host (index array)
+            n = len(rows)
+            bmap2d = dev_map.reshape(gh, gw)
+            # Partial remaps carry -1 holes; negative indexing would
+            # silently wrap to the wrong slab bytes, so a touched hole (the
+            # caller's page set failed to cover its rows) must surface as
+            # None — the engines then take the host fallback instead of
+            # serving garbage.
+            if n and (bmap2d[np.unique(rows // bh)] < 0).any():
+                return None
+            mode = self.mode()
+            if mode != "host":
+                # Pad with a *requested* row, not row 0: under partial
+                # residency row 0's block may be absent and must never be
+                # touched.
+                ids = np.full(_pad_pow2(max(n, 1)), rows[0] if n else 0,
+                              np.int32)
+                ids[:n] = rows
+                with tr.span("upload", kind="transfer"):
+                    ids_dev = self._put(jnp.asarray(ids))
+                    bmap_dev = self._put(jnp.asarray(bmap2d))
         if mode == "host":
-            with get_tracer().span("kernel", kind="kernel",
-                                   op="gather_rows", mode=mode, rows=n):
+            l = self.blocks_per_page
+            with tr.span("kernel", kind="kernel", op="gather_rows",
+                         mode=mode, rows=n):
                 slab = self.host_slab
                 flat_rows = slab.reshape(slab.shape[0] * l * bh, bw)
                 rb, off = rows // bh, rows % bh
                 out = flat_rows[bmap2d[rb] * bh + off[:, None]]  # [n,gw,bw]
                 return out.reshape(n, gw * bw)[:, :width]
-        # Pad with a *requested* row, not row 0: under partial residency
-        # row 0's block may be absent and must never be touched.
-        ids = np.full(_pad_pow2(max(n, 1)), rows[0] if n else 0, np.int32)
-        ids[:n] = rows
-        with get_tracer().span("kernel", kind="kernel", op="gather_rows",
-                               mode=mode, rows=n):
+        with tr.span("kernel", kind="kernel", op="gather_rows", mode=mode,
+                     rows=n):
             if mode == "pallas":
                 out = ops.dedup_embedding_striped(
-                    self._put(jnp.asarray(ids)), self.slab,
-                    self._put(jnp.asarray(bmap2d)), self.block_shape,
+                    ids_dev, self.slab, bmap_dev, self.block_shape,
                     width=width)
             else:
-                out = _gather_rows_xla(self.slab,
-                                       self._put(jnp.asarray(bmap2d)),
-                                       self._put(jnp.asarray(ids)),
+                out = _gather_rows_xla(self.slab, bmap_dev, ids_dev,
                                        block_shape=self.block_shape,
                                        width=width)
         return out if pad else out[:n]

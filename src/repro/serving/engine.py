@@ -456,18 +456,19 @@ class WeightServer:
         *before* the pool access, so every per-page ``on_load`` (e.g. a
         device-slab transfer) hits host memory instead of issuing its
         own backend round trip."""
-        self._sync_store()
-        page_ids = list(page_ids)
-        with get_tracer().span("fault_group", kind="storage", model=model,
-                               channel_name=self.storage.channel,
-                               pages=len(page_ids)) as sp:
+        tr = get_tracer()
+        with tr.span("fault_group", kind="storage", model=model,
+                     channel_name=self.storage.channel) as sp:
+            self._sync_store()
+            page_ids = list(page_ids)
             self.store.fault_pages(page_ids)
             misses = sum(not hit for hit in self._access(model, page_ids))
             t = self.storage.fetch_group_seconds(self.page_bytes, misses)
             t += self._charge_hbm(misses)
             t += self._charge_faults()
-            sp.set(misses=misses, bytes=misses * self.page_bytes,
-                   seconds=t)
+            if tr.enabled:
+                sp.set(pages=len(page_ids), misses=misses,
+                       bytes=misses * self.page_bytes, seconds=t)
         self.stats.pages_fetched += misses
         self.stats.fetch_seconds += t
         return t
@@ -482,8 +483,9 @@ class WeightServer:
         already device-side."""
         if self.device_pool is None or self.transfer != "grouped":
             return
-        self._sync_store()
-        self.device_pool.transfer.stage(page_ids)
+        with get_tracer().span("prestage", kind="transfer"):
+            self._sync_store()
+            self.device_pool.transfer.stage(page_ids)
 
     def transfer_snapshot(self) -> Optional[Dict[str, float]]:
         """Cumulative transfer-engine counters (None on the numpy
@@ -545,18 +547,22 @@ class WeightServer:
         faulted).  When given, only those pages must be resident — the
         working set may exceed the slab as long as each batch fits; when
         omitted, the tensor's whole page set must be resident."""
-        self._sync_store()
-        vt = self.store.virtual_tensor(model, tensor)
-        if pages is not None:
-            if not self.device_pool.pages_resident(pages):
-                return None
-            dev_map = self.device_pool.remap(vt, key=(model, tensor),
-                                             strict=False)
-        else:
-            dev_map = self.device_pool.remap(vt, key=(model, tensor))
-            if dev_map is None:
-                return None
-        return self.device_pool.gather_rows(dev_map, vt.grid, rows, pad=pad)
+        tr = get_tracer()
+        with tr.span("gather", kind="kernel", model=model):
+            with tr.span("gather_prep", kind="kernel"):
+                self._sync_store()
+                vt = self.store.virtual_tensor(model, tensor)
+                if pages is not None:
+                    if not self.device_pool.pages_resident(pages):
+                        return None
+                    dev_map = self.device_pool.remap(
+                        vt, key=(model, tensor), strict=False)
+                else:
+                    dev_map = self.device_pool.remap(vt, key=(model, tensor))
+                    if dev_map is None:
+                        return None
+            return self.device_pool.gather_rows(dev_map, vt.grid, rows,
+                                                pad=pad)
 
     def device_matmul(self, model: str, tensor: str, x):
         """``x @ W_virtual`` through dedup_matmul against the slab; None
@@ -631,6 +637,16 @@ class _PrefetchingEngine:
         if d_groups > 0:
             self.stats.group_sizes.append(d_pages / d_groups)
 
+    def _schedule(self, tr) -> Optional[ScheduledBatch]:
+        """The scheduler's next batch against the pool's residency."""
+        with tr.span("schedule", kind="policy",
+                     policy=self.scheduler.name) as sp:
+            batch = self.scheduler.next_batch(
+                self.server.pool.resident_pages())
+            if batch is not None and tr.enabled:
+                sp.set(seq=batch.seq, model=batch.model)
+            return batch
+
     def _prestage_next(self) -> None:
         """Double buffer: issue the NEXT queued batch's host->HBM staging
         transfer before computing the current batch, so the copy rides
@@ -704,15 +720,21 @@ class EmbeddingServingEngine(_PrefetchingEngine):
         server the router's placement decision rides along too (advisory:
         the server re-routes at run time, identically unless a repack
         intervened)."""
-        rows = np.unique(docs)
-        pages = self.server.embedding_rows_pages(model, self.embed_tensor,
-                                                 rows)
-        router = getattr(self.server, "router", None)
-        shard = router.route(pages, record=False).shard \
-            if router is not None else None
-        self.scheduler.submit(model, docs, pages=pages,
-                              pages_gen=self.server.store.pack_generation,
-                              shard=shard)
+        with get_tracer().span("submit", kind="engine", model=model,
+                               seq=self.scheduler.next_seq):
+            pages = self._page_map(model, docs)
+            router = getattr(self.server, "router", None)
+            shard = router.route(pages, record=False).shard \
+                if router is not None else None
+            self.scheduler.submit(model, docs, pages=pages,
+                                  pages_gen=self.server.store.pack_generation,
+                                  shard=shard)
+
+    def _page_map(self, model: str, docs: np.ndarray) -> List[int]:
+        """The page set of ``docs``'s rows (page-map arithmetic only)."""
+        with get_tracer().span("page_map", kind="engine"):
+            return self.server.embedding_rows_pages(
+                model, self.embed_tensor, np.unique(docs))
 
     def _head_dev(self, model: str):
         head = self._dev_heads.get(model)
@@ -731,13 +753,11 @@ class EmbeddingServingEngine(_PrefetchingEngine):
                 and self.server.store.packing_current(batch.pages_gen):
             pages = sorted(batch.pages)
         else:
-            pages = self.server.embedding_rows_pages(
-                model, self.embed_tensor, np.unique(docs))
+            pages = self._page_map(model, docs)
         snap = self._transfer_snap()
         degraded = False
         tr = get_tracer()
-        with tr.span("fetch", kind="engine", model=model,
-                     pages=len(pages)) as fsp:
+        with tr.span("fetch", kind="engine", model=model) as fsp:
             try:
                 if self.overlap:
                     fetch_t = self.server.access_pages_grouped(model, pages)
@@ -750,7 +770,8 @@ class EmbeddingServingEngine(_PrefetchingEngine):
                 degraded = True
                 self.stats.degraded_batches += 1
                 fetch_t = self.server._charge_faults()
-            fsp.set(seconds=fetch_t, degraded=degraded)
+            if tr.enabled:
+                fsp.set(pages=len(pages), seconds=fetch_t, degraded=degraded)
         if self.prefetcher is not None:
             self.prefetcher.note_demand(pages)     # lookahead hit accounting
         # double buffer: next batch's host->HBM copy issues now, rides
@@ -780,9 +801,12 @@ class EmbeddingServingEngine(_PrefetchingEngine):
                     if isinstance(emb, np.ndarray):
                         logits = emb.mean(axis=1) @ self.heads[model]
                     else:
-                        # repro: allow-host (batch boundary: logits leave)
-                        logits = np.asarray(_tok_logits(
-                            emb, self._head_dev(model)))
+                        head = self._head_dev(model)
+                        with tr.span("to_host", kind="engine"):
+                            with tr.span("head", kind="engine"):
+                                out = _tok_logits(emb, head)
+                            # repro: allow-host (batch boundary: logits leave)
+                            logits = np.asarray(out)
                     self.stats.device_batches += 1
             csp.set(device=logits is not None)
             if logits is None:
@@ -820,15 +844,13 @@ class EmbeddingServingEngine(_PrefetchingEngine):
         tr = get_tracer()
         n = 0
         while self.scheduler.pending():
-            batch = self.scheduler.next_batch(
-                self.server.pool.resident_pages())
-            if batch is None:
-                break
-            if tr.enabled:
-                tr.event("schedule", kind="policy",
-                         policy=self.scheduler.name, model=batch.model)
-            self._infer(batch)
-            self._maybe_prefetch()
+            with tr.span("run", kind="engine") as sp:
+                batch = self._schedule(tr)
+                if batch is None:
+                    break
+                sp.set(seq=batch.seq, model=batch.model)
+                self._infer(batch)
+                self._maybe_prefetch()
             n += 1
             if max_batches and n >= max_batches:
                 break
@@ -979,13 +1001,9 @@ class LMServingEngine(_PrefetchingEngine):
         n = 0
         results = []
         while self.scheduler.pending():
-            batch = self.scheduler.next_batch(
-                self.server.pool.resident_pages())
+            batch = self._schedule(tr)
             if batch is None:
                 break
-            if tr.enabled:
-                tr.event("schedule", kind="policy",
-                         policy=self.scheduler.name, model=batch.model)
             prompts, steps = batch.payload
             snap = self._transfer_snap()
             fetch_t = self._load_model(batch.model, grouped=self.overlap)

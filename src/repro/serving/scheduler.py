@@ -52,6 +52,11 @@ class BatchScheduler:
         self._seq = 0
 
     # -- submission ----------------------------------------------------------
+    @property
+    def next_seq(self) -> int:
+        """The ``seq`` the next submitted batch will carry."""
+        return self._seq
+
     def submit(self, model: str, payload, pages: Optional[Iterable] = None,
                pages_gen: Optional[int] = None,
                shard: Optional[int] = None) -> ScheduledBatch:

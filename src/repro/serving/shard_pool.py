@@ -618,6 +618,12 @@ class _ShardedPoolView:
         return self._sum("evictions")
 
     @property
+    def victim_scanned(self) -> int:
+        return self._sum("victim_scanned")
+
+    register_into = BufferPool.register_into
+
+    @property
     def prefetches(self) -> int:
         return self._sum("prefetches")
 

@@ -199,31 +199,33 @@ class TransferEngine:
         the copy rides under compute — JAX dispatch returns immediately.
         Commit happens later, when the buffer pool actually admits the
         pages (:meth:`load_group`)."""
-        self.pool.store.packing                  # settle before gen read
-        self._fresh_pending()
-        missing = self._missing(pids)
-        if not missing:
-            return None
-        key = frozenset(missing)
-        hit = self._pending.get(key)
-        if hit is not None:
-            return hit
-        for staged in self._pending.values():    # already covered by one?
-            if key <= staged.index.keys():
-                return staged
-        with get_tracer().span("stage", kind="transfer",
-                               pages=len(missing),
-                               bytes=len(missing) * self.page_nbytes):
+        tr = get_tracer()
+        with tr.span("stage", kind="transfer") as sp:
+            self.pool.store.packing              # settle before gen read
+            self._fresh_pending()
+            missing = self._missing(pids)
+            if not missing:
+                return None
+            key = frozenset(missing)
+            hit = self._pending.get(key)
+            if hit is not None:
+                return hit
+            for staged in self._pending.values():  # already covered by one?
+                if key <= staged.index.keys():
+                    return staged
+            if tr.enabled:
+                sp.set(pages=len(missing),
+                       bytes=len(missing) * self.page_nbytes)
             stack = self._stack(missing)
             dev = None if self.pool.mode() == "host" \
                 else self._to_device(stack)
-        pg = PendingGroup({p: i for i, p in enumerate(missing)}, stack, dev,
-                          self.pool.store.pack_generation)
-        self._pending[key] = pg
-        while len(self._pending) > self.max_pending:
-            self._pending.popitem(last=False)
-        self.stats.staged_groups += 1
-        return pg
+            pg = PendingGroup({p: i for i, p in enumerate(missing)}, stack,
+                              dev, self.pool.store.pack_generation)
+            self._pending[key] = pg
+            while len(self._pending) > self.max_pending:
+                self._pending.popitem(last=False)
+            self.stats.staged_groups += 1
+            return pg
 
     # ------------------------------------------------------------- commit --
     def _full_cover(self, missing: List[int]) -> Optional[PendingGroup]:
@@ -243,17 +245,19 @@ class TransferEngine:
         :meth:`stage` commits from the already in-flight device bytes
         (the overlapped path, counted in ``overlapped_bytes``); anything
         else is staged now.  Returns pages loaded."""
-        self._fresh_pending()
-        missing = self._missing(pids)
-        if not missing:
-            return 0
-        if len(missing) > len(self.pool._free):
-            raise RuntimeError(
-                f"group of {len(missing)} pages exceeds the slab's "
-                f"{len(self.pool._free)} free slots")
-        with get_tracer().span("load_group", kind="transfer",
-                               pages=len(missing),
-                               bytes=len(missing) * self.page_nbytes) as sp:
+        tr = get_tracer()
+        with tr.span("load_group", kind="transfer") as sp:
+            self._fresh_pending()
+            missing = self._missing(pids)
+            if not missing:
+                return 0
+            if len(missing) > len(self.pool._free):
+                raise RuntimeError(
+                    f"group of {len(missing)} pages exceeds the slab's "
+                    f"{len(self.pool._free)} free slots")
+            if tr.enabled:
+                sp.set(pages=len(missing),
+                       bytes=len(missing) * self.page_nbytes)
             pg = self._full_cover(missing)
             overlapped = 0
             if pg is not None:
@@ -314,6 +318,10 @@ class TransferEngine:
                               time.perf_counter() - t0,
                               overlapped_bytes=overlapped)
             sp.set(overlapped_bytes=overlapped)
+            # the staging stacks (tens of MB on a base switch) are freed
+            # here, inside the span, not on return: the free is part of
+            # the commit's host time
+            del pg, host_stack
         return len(missing)
 
     def record_single(self, seconds: float) -> None:

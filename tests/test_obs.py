@@ -14,9 +14,10 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from repro.launch.serve import REPORT_FIELDS, build_store
-from repro.obs import (NULL_TRACER, MetricsRegistry, Tracer, get_tracer,
-                       to_chrome_trace, use_tracer,
+from repro.core.bufferpool import BufferPool, PoolConfig
+from repro.launch.serve import REPORT_FIELDS, _build_registry, build_store
+from repro.obs import (NULL_TRACER, HostClock, MetricsRegistry, Tracer,
+                       get_tracer, to_chrome_trace, use_tracer,
                        validate_chrome_trace, write_trace)
 from repro.obs.export import load_trace
 from repro.data.pipeline import SyntheticTextTask
@@ -226,6 +227,212 @@ def test_tracing_on_vs_off_is_bit_identical():
     # and the virtual clocks agree to the last ulp
     assert fe_on.clock.now == fe_off.clock.now
     assert fe_on.clock.channels == fe_off.clock.channels
+
+
+# ------------------------------------------------------------ host clock --
+def test_host_tracer_nests_carries_seq_and_reads_perf_counter(monkeypatch):
+    ticks = iter(float(t) for t in range(100, 200))
+    monkeypatch.setattr("repro.obs.trace.time.perf_counter",
+                        lambda: next(ticks))
+    tr = Tracer(clock=HostClock())
+    with tr.span("run", kind="engine") as run:
+        run.set(seq=7)
+        with tr.span("fetch", kind="engine") as fetch:
+            with tr.span("evict", kind="pool") as ev:
+                pass
+        with tr.span("other", seq=9) as other:
+            pass
+    assert (fetch.parent, ev.parent) == (run.sid, fetch.sid)
+    assert fetch.attrs["seq"] == ev.attrs["seq"] == 7   # inherited
+    assert other.attrs["seq"] == 9                      # its own wins
+    # one perf_counter read at each open and each close, in order
+    assert (run.start_t, fetch.start_t, ev.start_t) == (100.0, 101.0, 102.0)
+    assert (ev.end_t, fetch.end_t, run.end_t) == (103.0, 104.0, 107.0)
+    assert [s.name for s in tr.spans()] == ["evict", "fetch", "other", "run"]
+
+
+def test_host_tracer_spans_land_in_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer(clock=HostClock())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with use_tracer(tr):
+            with get_tracer().span("outer", kind="engine", seq=3,
+                                   model="m", rows=[1, 2]) as sp:
+                sp.set(pages=5)
+                with get_tracer().span("inner", kind="kernel"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path[0]).planes:
+        if plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                for e in ln.events:
+                    if e.name.startswith("repro."):
+                        events[e.name] = (e.start_ns, e.end_ns,
+                                          dict(e.stats))
+    assert set(events) == {"repro.outer", "repro.inner"}
+    # scalar attrs ride along (rows, a list, does not); set() reaches it
+    assert events["repro.outer"][2] == {"seq": 3, "model": "m", "pages": 5}
+    assert events["repro.inner"][2] == {"seq": 3}
+    o0, o1, _ = events["repro.outer"]
+    i0, i1, _ = events["repro.inner"]
+    assert o0 <= i0 <= i1 <= o1
+
+
+def test_host_tracer_rejects_charge():
+    tr = Tracer(clock=HostClock())
+    with pytest.raises(ValueError, match="host-clock"):
+        tr.span("fetch", channel="storage", charge=0.1)
+    with tr.span("fetch", kind="engine"):       # attributed spans are fine
+        pass
+    assert tr.open_spans() == [] and len(tr.spans()) == 1
+
+
+def _device_engine(tracer, kernel_mode):
+    task, store, heads = _scenario(vocab=1024, l=2)     # 18 pages
+    server = WeightServer(store, 16,
+                          storage=StorageModel("dram"), backend="device",
+                          kernel_mode=kernel_mode)
+    engine = EmbeddingServingEngine(server, heads, scheduler="round_robin",
+                                    overlap=True)
+    logits = []
+    with use_tracer(tracer):
+        for b in range(6):
+            v = b % 3
+            docs, _ = task.sample(8, variant=v, seed=300 + b)
+            engine.submit(f"word2vec-v{v}", docs)
+            engine.run(max_batches=1)
+            logits.append(engine.last_logits.copy())
+    return engine, logits
+
+
+@pytest.mark.parametrize("kernel_mode", ["host", "xla"])
+def test_host_tracing_on_vs_off_is_bit_identical(kernel_mode):
+    tracer = Tracer(clock=HostClock())
+    on, logits_on = _device_engine(tracer, kernel_mode)
+    off, logits_off = _device_engine(NULL_TRACER, kernel_mode)
+    for a, b in zip(logits_on, logits_off):
+        np.testing.assert_array_equal(a, b)
+    pool_on, pool_off = on.server.pool, off.server.pool
+    assert (pool_on.hits, pool_on.misses, pool_on.evictions,
+            pool_on.victim_scanned) == (pool_off.hits, pool_off.misses,
+                                        pool_off.evictions,
+                                        pool_off.victim_scanned)
+    assert on.stats.device_batches == off.stats.device_batches == 6
+    # every span of a request carries its batch's seq
+    names = {sp.name for sp in tracer.spans()}
+    assert {"submit", "page_map", "run", "schedule", "fetch",
+            "fault_group", "pool_group", "compute", "gather",
+            "gather_prep", "kernel"} <= names
+    if kernel_mode == "xla":
+        assert {"upload", "to_host", "head"} <= names
+    assert pool_on.evictions and tracer.find(name="evict", kind="pool")
+    assert tracer.find(name="schedule", kind="policy")
+    for sp in tracer.spans():
+        assert isinstance(sp.attrs.get("seq"), int), sp.name
+    runs = tracer.find(name="run")
+    assert sorted(sp.attrs["seq"] for sp in runs) == list(range(6))
+
+
+def test_get_tracer_follows_the_profiler(tmp_path):
+    import glob
+    import threading
+
+    import jax
+    from jax.profiler import ProfileData
+    assert get_tracer() is NULL_TRACER            # no profile, no tracer
+    installed = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tr = get_tracer()
+        assert tr is not NULL_TRACER and isinstance(tr.clock, HostClock)
+        assert get_tracer() is tr                 # one per thread
+        other = []
+        t = threading.Thread(target=lambda: other.append(get_tracer()))
+        t.start()
+        t.join()
+        assert other[0] is not tr and isinstance(other[0].clock, HostClock)
+        with use_tracer(installed):               # an installed one wins
+            assert get_tracer() is installed
+        with get_tracer().span("run", kind="engine", seq=2):
+            with get_tracer().span("evict", kind="pool"):
+                pass
+        assert tr.open_spans() == []
+    finally:
+        jax.profiler.stop_trace()
+    assert get_tracer() is NULL_TRACER
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name: dict(e.stats)
+             for plane in ProfileData.from_file(path[0]).planes
+             if plane.name.startswith("/host:")
+             for ln in plane.lines for e in ln.events
+             if e.name.startswith("repro.")}
+    assert names == {"repro.run": {"seq": 2}, "repro.evict": {"seq": 2}}
+
+
+def test_evict_span_carries_the_pool_counters():
+    pool = BufferPool(PoolConfig(2, "lru"))
+    tr = Tracer(clock=HostClock())
+    with use_tracer(tr):
+        for p in (0, 1, 2, 3):
+            pool.access("m", p)
+    marks = [(sp.attrs["victim_scanned"], sp.attrs["evictions"])
+             for sp in tr.find(name="evict", kind="pool")]
+    assert marks == [(2, 1), (4, 2)]
+    assert marks[-1] == (pool.victim_scanned, pool.evictions)
+    off = BufferPool(PoolConfig(2, "lru"))        # tracing off: same counts
+    for p in (0, 1, 2, 3):
+        off.access("m", p)
+    assert (off.victim_scanned, off.evictions) == marks[-1]
+
+
+# ----------------------------------------------------------- pool counter --
+@pytest.mark.parametrize("policy,passes", [
+    ("lru", lambda res, sets: res),
+    ("lfu", lambda res, sets: 2 * res),
+    ("optimized_mru", lambda res, sets: 2 * res + sets * res),
+])
+def test_victim_scanned_counts_every_pass(policy, passes):
+    # 4 resident pages in 2 locality sets, nothing pinned
+    pool = BufferPool(PoolConfig(4, policy),
+                      page_locality={p: p % 2 for p in range(10)})
+    for p in range(4):
+        pool.access("m", p)
+    assert pool.victim_scanned == 0            # no eviction yet
+    pool.access("m", 4)
+    assert pool.evictions == 1
+    assert pool.victim_scanned == passes(4, 2)
+    pool.access("m", 5)
+    assert pool.victim_scanned == 2 * passes(4, 2)
+    pool.reset_stats()
+    assert pool.victim_scanned == 0
+
+
+def test_pool_register_into_exposes_victim_scanned():
+    pool = BufferPool(PoolConfig(2, "lru"))
+    reg = MetricsRegistry()
+    pool.register_into(reg, "pool")
+    assert reg.names("counter") == ["pool.hits", "pool.misses",
+                                    "pool.evictions", "pool.victim_scanned"]
+    before = reg.snapshot()
+    for p in (0, 1, 0, 2):
+        pool.access("m", p)
+    assert reg.diff(before) == {"pool.hits": 1.0, "pool.misses": 3.0,
+                                "pool.evictions": 1.0,
+                                "pool.victim_scanned": 2.0}
+    # launch/serve.py's registry carries the pool's counters too
+    task, store, heads = _scenario(num_models=2)
+    server = WeightServer(store, 4, storage=StorageModel("dram"))
+    engine = EmbeddingServingEngine(server, heads)
+    serve_reg = _build_registry(engine.stats, server, engine, None)
+    assert "pool.victim_scanned" in serve_reg
+    assert serve_reg.kind("pool.victim_scanned") == "counter"
 
 
 # -------------------------------------------------------------- exporters --
