@@ -101,6 +101,17 @@ class BufferPool:
         self._set_lambda: Dict[Hashable, float] = defaultdict(float)
         self._set_last: Dict[Hashable, int] = {}
         self._pinned: Set[PageId] = set()
+        # Victim index of the locality-set policies, built at the first
+        # eviction (a pool that never evicts pays nothing for it) and
+        # dropped by invalidate_resident: per locality set, its evictable
+        # (resident, unpinned) pages in ``resident`` order, and each
+        # resident page's position in that order (monotone; below zero
+        # for a prefetch inserted at the LRU end).
+        self._sets: Optional[Dict[Hashable,
+                                  "OrderedDict[PageId, None]"]] = None
+        self._pos: Dict[PageId, int] = {}
+        self._pos_hi = 0
+        self._pos_lo = 0
 
     # ------------------------------------------------------------- metrics --
     @property
@@ -135,6 +146,7 @@ class BufferPool:
         backing store was repacked, so page ids no longer name the same
         bytes.  ``on_evict`` still fires per page so an attached device
         slab frees its slots."""
+        self._sets, self._pos = None, {}
         for page in list(self.resident):
             del self.resident[page]
             if self.on_evict:
@@ -162,16 +174,20 @@ class BufferPool:
         if page in self.resident:
             self.hits += 1
             self.resident.move_to_end(page)      # LRU order maintenance
+            if self._sets is not None:
+                self._index_put(page)
             return True
         self.misses += 1
         while len(self.resident) >= self.cfg.capacity_pages:
             self._evict_one()
         self.resident[page] = None
+        if self._sets is not None:
+            self._index_put(page)
         try:
             self._note_load(page)
         except BaseException:
             # failed physical load: un-admit (no on_evict — no slot held)
-            self.resident.pop(page, None)
+            self._drop(page)
             raise
         return False
 
@@ -205,7 +221,7 @@ class BufferPool:
                 self.on_load_group(list(batch))
             except BaseException:
                 for page in batch:
-                    self.resident.pop(page, None)
+                    self._drop(page)
                 raise
         elif self.on_load:
             for i, page in enumerate(batch):
@@ -213,7 +229,7 @@ class BufferPool:
                     self.on_load(page)
                 except BaseException:
                     for p in batch[i:]:
-                        self.resident.pop(p, None)
+                        self._drop(p)
                     raise
 
     @contextlib.contextmanager
@@ -251,6 +267,10 @@ class BufferPool:
                 f"group of {len(set(pages))} pages exceeds pool capacity "
                 f"{self.cfg.capacity_pages}")
         self._pinned = set(pages)
+        if self._sets is not None:
+            for p in self._pinned:
+                if p in self.resident:
+                    self._unlist(p)
         tr = get_tracer()
         try:
             with tr.span("pool_group", kind="pool", model=model,
@@ -261,7 +281,9 @@ class BufferPool:
                     sp.set(hits=sum(hits))
                 return hits
         finally:
-            self._pinned = set()
+            pinned, self._pinned = self._pinned, set()
+            if self._sets is not None:
+                self._relist(pinned)
 
     def _update_rate(self, model: ModelId) -> None:
         last = self._last_access.get(model)
@@ -297,52 +319,110 @@ class BufferPool:
     def _cost(self, p_reuse: float) -> float:
         return self.cfg.c_w + p_reuse * self.cfg.c_r   # Eq. 1
 
-    def _victim_in_set(self, pages, inner: str) -> PageId:
-        # Recency order within the set, using resident OrderedDict order.
-        self.victim_scanned += len(self.resident)
-        ordered = [p for p in self.resident if p in pages]
-        return ordered[-1] if inner == "mru" else ordered[0]
-
     def _pick_victim(self) -> PageId:
         """The policy's victim.  ``victim_scanned`` counts every page
-        each pass here looks at: the resident set, the evictable list,
-        and the resident set again for each locality set's candidate."""
+        looked at here: for ``lru``/``mru``/``lfu`` the resident set and
+        the evictable list; for the locality-set policies one candidate a
+        set, plus the resident set once when the index is built."""
         pol = self.cfg.policy
-        self.victim_scanned += len(self.resident)
-        evictable = [p for p in self.resident if p not in self._pinned]
-        if not evictable:
-            raise RuntimeError("every resident page is pinned; "
-                               "group exceeds usable capacity")
-        if pol == "lru":
-            return evictable[0]
-        if pol == "mru":
-            return evictable[-1]
-        if pol == "lfu":
+        if pol in ("lru", "mru", "lfu"):
+            self.victim_scanned += len(self.resident)
+            evictable = [p for p in self.resident if p not in self._pinned]
+            if not evictable:
+                raise RuntimeError("every resident page is pinned; "
+                                   "group exceeds usable capacity")
+            if pol == "lru":
+                return evictable[0]
+            if pol == "mru":
+                return evictable[-1]
             self.victim_scanned += len(evictable)
             return min(evictable, key=lambda p: (self.meta[p].freq,
                                                  self.meta[p].last_tick))
-        inner = "mru" if pol.endswith("mru") else "lru"
-        by_set: Dict[Hashable, Set[PageId]] = defaultdict(set)
-        self.victim_scanned += len(evictable)
-        for p in evictable:
-            by_set[self.meta[p].locality_set].add(p)
-        best, best_cost = None, None
-        for ls, pages in by_set.items():
-            cand = self._victim_in_set(pages, inner)
-            if pol.startswith("optimized"):
+        if self._sets is None:
+            self._build_index()
+        mru = pol.endswith("mru")
+        optimized = pol.startswith("optimized")
+        self.victim_scanned += len(self._sets)
+        best, best_key = None, None
+        for ls, pages in self._sets.items():
+            # the set's own policy picks its candidate; equal costs go to
+            # the set whose LRU-end page comes first in ``resident``
+            first = next(iter(pages))
+            cand = next(reversed(pages)) if mru else first
+            if optimized:
                 pr = self._p_reuse_eq2(cand)     # Eq. 2 (shared-page aware)
             else:
                 pr = self._p_reuse_set(ls)       # original locality-set
-            cost = self._cost(pr)
-            if best_cost is None or cost < best_cost:
-                best, best_cost = cand, cost
+            key = (self._cost(pr), self._pos[first])
+            if best_key is None or key < best_key:
+                best, best_key = cand, key
+        if best is None:
+            raise RuntimeError("every resident page is pinned; "
+                               "group exceeds usable capacity")
         return best
+
+    # ------------------------------------------------------- victim index --
+    def _build_index(self) -> None:
+        self.victim_scanned += len(self.resident)
+        self._sets, self._pos = {}, {}
+        for i, p in enumerate(self.resident):
+            self._pos[p] = i
+            if p not in self._pinned:
+                self._sets.setdefault(self.meta[p].locality_set,
+                                      OrderedDict())[p] = None
+        self._pos_hi, self._pos_lo = len(self.resident), 0
+
+    def _index_put(self, page: PageId, lru_end: bool = False) -> None:
+        """Follow ``page``'s move to an end of ``resident``."""
+        if lru_end:
+            self._pos_lo -= 1
+            self._pos[page] = self._pos_lo
+        else:
+            self._pos[page] = self._pos_hi
+            self._pos_hi += 1
+        if page not in self._pinned:
+            pages = self._sets.setdefault(self.meta[page].locality_set,
+                                          OrderedDict())
+            pages[page] = None
+            pages.move_to_end(page, last=not lru_end)
+
+    def _unlist(self, page: PageId) -> None:
+        """Take ``page`` out of its set's evictable list."""
+        ls = self.meta[page].locality_set
+        pages = self._sets.get(ls)
+        if pages is not None and page in pages:
+            del pages[page]
+            if not pages:
+                del self._sets[ls]
+
+    def _relist(self, pages: Iterable[PageId]) -> None:
+        """Put the resident pages of a released pin back at the MRU end
+        of their sets, in recency order.  A group cut short leaves some
+        untouched pages mid-order; the index is then rebuilt at the next
+        eviction instead."""
+        back = sorted((p for p in pages if p in self.resident),
+                      key=self._pos.__getitem__)
+        for p in back:
+            lst = self._sets.setdefault(self.meta[p].locality_set,
+                                        OrderedDict())
+            if lst and self._pos[next(reversed(lst))] > self._pos[p]:
+                self._sets = None
+                return
+            lst[p] = None
+
+    def _drop(self, page: PageId) -> None:
+        """Take ``page`` out of ``resident`` and the index, if there."""
+        if page in self.resident:
+            del self.resident[page]
+            if self._sets is not None:
+                self._unlist(page)
+                del self._pos[page]
 
     def _evict_one(self) -> None:
         tr = get_tracer()
         with tr.span("evict", kind="pool") as sp:
             victim = self._pick_victim()
-            del self.resident[victim]
+            self._drop(victim)
             self.evictions += 1
             if self.on_evict:
                 self.on_evict(victim)
@@ -382,15 +462,17 @@ class BufferPool:
         # end for *mru policies, the LRU end otherwise): a prefetched page
         # has not been *used* yet, so until a demand access promotes it,
         # it must stay the most evictable page — not the most protected.
+        mru = self.cfg.policy.endswith("mru")
         self.resident[page] = None
-        self.resident.move_to_end(page,
-                                  last=self.cfg.policy.endswith("mru"))
+        self.resident.move_to_end(page, last=mru)
+        if self._sets is not None:
+            self._index_put(page, lru_end=not mru)
         m.last_tick = max(m.last_tick, 0)
         self.prefetches += 1
         try:
             self._note_load(page)
         except BaseException:
-            self.resident.pop(page, None)
+            self._drop(page)
             raise
         return True
 

@@ -393,12 +393,13 @@ def test_evict_span_carries_the_pool_counters():
 
 
 # ----------------------------------------------------------- pool counter --
-@pytest.mark.parametrize("policy,passes", [
-    ("lru", lambda res, sets: res),
-    ("lfu", lambda res, sets: 2 * res),
-    ("optimized_mru", lambda res, sets: 2 * res + sets * res),
+@pytest.mark.parametrize("policy,first,second", [
+    ("lru", 4, 4),                 # the resident set
+    ("lfu", 8, 8),                 # the resident set, the evictable list
+    ("optimized_mru", 4 + 2, 2),   # the index's build, one page a set
+    ("locality_lru", 4 + 2, 2),
 ])
-def test_victim_scanned_counts_every_pass(policy, passes):
+def test_victim_scanned_counts_every_pass(policy, first, second):
     # 4 resident pages in 2 locality sets, nothing pinned
     pool = BufferPool(PoolConfig(4, policy),
                       page_locality={p: p % 2 for p in range(10)})
@@ -407,9 +408,9 @@ def test_victim_scanned_counts_every_pass(policy, passes):
     assert pool.victim_scanned == 0            # no eviction yet
     pool.access("m", 4)
     assert pool.evictions == 1
-    assert pool.victim_scanned == passes(4, 2)
+    assert pool.victim_scanned == first
     pool.access("m", 5)
-    assert pool.victim_scanned == 2 * passes(4, 2)
+    assert pool.victim_scanned == first + second
     pool.reset_stats()
     assert pool.victim_scanned == 0
 
