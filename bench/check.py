@@ -21,20 +21,11 @@ from __future__ import annotations
 
 import importlib
 from collections import defaultdict
-from typing import Dict, List, Sequence
+from typing import Dict, Sequence
 
 import numpy as np
 
 import fleet
-from traffic import SAMPLE, seed_rng
-
-
-def sample(done: Sequence, k: int, seed: int) -> List:
-    """``k`` answered requests drawn from the seed (all when fewer)."""
-    if len(done) <= k:
-        return list(done)
-    idx = seed_rng(seed, SAMPLE).choice(len(done), k, replace=False)
-    return [done[i] for i in sorted(idx)]
 
 
 def reference_module(cfg: dict):
@@ -90,6 +81,6 @@ def compare(cfg: dict, seed: int, done: Sequence, heads) -> Dict[str, float]:
     worst, n = 0.0, 0
     for d in done:
         lg, scale = refs[d.request.index]
-        worst = max(worst, logit_err(d.logits, lg, scale))
+        worst = max(worst, logit_err(d.answer, lg, scale))
         n += lg.size
     return {"logit_err": worst, "logits": n}

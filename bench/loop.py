@@ -1,11 +1,12 @@
-"""Closed-loop callers driving ``EmbeddingServingEngine.submit`` / ``run``.
+"""Closed-loop callers driving a served engine's ``submit`` / ``run``.
 
 Each of ``callers`` callers keeps one request outstanding: it sends its
 next request the moment its previous answer is on the host.  One thread
 plays all callers: after every ``engine.run(max_batches=1)`` the request
-the scheduler picked is complete (its logits are ``engine.last_logits``,
-copied to the host by the engine), and its caller sends again.  A
+the scheduler picked is complete (its answer, on the host, is what the
+kind's ``answer`` reads off the engine), and its caller sends again.  A
 request's latency runs from its send to that moment, on the host clock.
+The kind (``kinds/``) gives ``send(engine, req)`` and ``answer(engine)``.
 
 The loop learns which request a batch is by wrapping the engine's
 scheduler's ``submit`` and ``next_batch`` (a batch's ``seq``).
@@ -13,25 +14,28 @@ scheduler's ``submit`` and ``next_batch`` (a batch's ``seq``).
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 
 class Done:
     """One answered request."""
-    __slots__ = ("request", "sent", "done", "logits")
+    __slots__ = ("request", "sent", "done", "answer")
 
     def __init__(self, request, sent: float):
         self.request = request
         self.sent = sent
         self.done = 0.0
-        self.logits: Optional[np.ndarray] = None
+        self.answer: Optional[np.ndarray] = None
 
 
 class ClosedLoop:
-    def __init__(self, engine, requests: List, callers: int):
+    def __init__(self, engine, requests: List, callers: int,
+                 send: Callable, answer: Callable):
         self.engine = engine
+        self._send = send
+        self._answer = answer
         self.requests = requests
         self.callers = callers
         self.next = 0                    # index of the next request to send
@@ -59,7 +63,7 @@ class ClosedLoop:
         req = self.requests[self.next]
         self.next += 1
         rec = Done(req, time.perf_counter())
-        self.engine.submit(req.model, req.docs)
+        self._send(self.engine, req)
         self.inflight[self._submitted.seq] = rec
 
     def step(self) -> Done:
@@ -69,7 +73,7 @@ class ClosedLoop:
         if self._popped is None:
             raise RuntimeError("engine.run served no batch")
         rec = self.inflight.pop(self._popped.seq)
-        rec.logits = self.engine.last_logits
+        rec.answer = self._answer(self.engine)
         rec.done = time.perf_counter()
         return rec
 

@@ -6,32 +6,38 @@
 
 Everything is found by name.  The cell (``workloads``) names a
 configuration (``configs`` -> its file) and a traffic mix
-(``bench/traffic/<traffic>.json``); each metric the cell reports is read
-by ``bench/metrics/<metric>.py`` (:func:`metric_reader`).  Adding a cell,
-a mix or a metric adds files and entries and edits none.
+(``bench/traffic/<traffic>.json``); the configuration names its kind
+(``"kind"``, default ``embedding``), the module ``bench/kinds/<kind>.py``
+that builds, serves and checks that kind of model (:func:`kind_module`;
+the contract is in ``bench/kinds/__init__.py``); each metric the cell
+reports is read by ``bench/metrics/<metric>.py`` (:func:`metric_reader`).
+Adding a cell, a mix, a metric, or a configuration of a new kind (its
+kind module, reference, mixes and metrics) adds files and entries and
+edits none.
 
 A run, in one process on the chips it finds:
 
-  set-up   make the fleet's weights from the seed (on the device), register
-           every variant through ``DedupDB`` (Alg. 1), commit the store to
-           ``sqlite:///<checkout>/.scratch/bench/<cell>.db``, reopen it with
-           ``DedupDB.open``, build the engine (``round_robin``, overlapped,
-           ``WeightServer(backend="device")``), fill the slab, serve the
-           mix's warm-up requests, then ``gc.collect()`` and
+  set-up   the kind makes the fleet's weights from the seed, registers
+           every variant through ``DedupDB`` (Alg. 1) and commits the
+           store to ``sqlite:///<checkout>/.scratch/bench/<cell>.db``,
+           reopens it with ``DedupDB.open``, builds the engine on it, makes
+           the requests and serves the warm-up ones; then the gathers from
+           staged page stacks are compiled, ``gc.collect()`` and
            ``gc.freeze()``;
   window   closed-loop callers for ``--seconds`` (``loop.py``); with
-           ``--trace 1`` under the profiler, with host spans around the
-           program's layers (``spans.py``);
-  check    the sampled answers against the plain reference (``check.py``),
-           after the program's state is freed.
+           ``--trace 1`` under the profiler, with the kind's host spans
+           around the program's layers;
+  check    the sampled answers against the plain reference (the kind's
+           ``check``), after the program's state is freed.
 
 The last line of standard output is the result: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
 with ``--trace 1`` its per-layer ones), ``device`` and, traced,
 ``breakdown``; last comes ``checks``, each compared number beside its
 limit, which are also the last lines on standard error.  A run is
-correct when the sampled answers match the reference, no request was
-answered through a host fallback, and nothing compiled in the window.
+correct when the sampled answers match the reference, no batch was
+answered off the device path (the kind's ``failed_batches``), and
+nothing compiled in the window.
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 2 and prints no result.
 """
@@ -43,6 +49,7 @@ T_START = time.perf_counter()
 
 import argparse        # noqa: E402
 import gc              # noqa: E402
+import importlib       # noqa: E402
 import importlib.util  # noqa: E402
 import json            # noqa: E402
 import os              # noqa: E402
@@ -135,19 +142,10 @@ def setup_jax(cfg: dict) -> str:
     return cache
 
 
-def slab_pages(store, cfg: dict, mix: dict) -> int:
-    """The slab's capacity in pages, as the mix sizes it."""
-    slab = mix["slab"]
-    if slab["kind"] == "fleet":
-        return store.num_pages()
-    if slab["kind"] == "family_plus":
-        import fleet
-        fams = [set().union(*(store.model_pages(m) for m in names))
-                for names in fleet.variant_names(cfg)]
-        big = max(fams, key=len)
-        rest = set().union(*(f for f in fams if f is not big)) - big
-        return len(big) + int(round(slab["other_frac"] * len(rest)))
-    raise ValueError(f"unknown slab kind {slab['kind']!r}")
+def kind_module(name: str):
+    """``bench/kinds/<name>.py``: what a configuration of that kind
+    builds, serves and checks."""
+    return importlib.import_module("kinds." + name)
 
 
 def committed_page_bytes(path: str) -> int:
@@ -161,25 +159,17 @@ def committed_page_bytes(path: str) -> int:
         con.close()
 
 
-def open_engine(url: str, cfg: dict, mix: dict, heads: dict):
-    """Reopen the committed store as users open one and build the served
-    engine on it: the slab sized by the mix, ``round_robin``, overlapped
-    fetches, the device backend."""
-    from repro.db import DedupDB
-    db = DedupDB.open(url)
-    engine = db.serve_embedding(heads,
-                                capacity_pages=slab_pages(db.store, cfg, mix),
-                                scheduler="round_robin", overlap=True,
-                                compute_backend="device")
-    return db, engine
-
-
 class StagedSizes:
-    """Counts the sizes of the page stacks the transfer engine stages
-    (``TransferEngine.stage``), for the whole run."""
+    """Counts the sizes of the page stacks the transfer engines of the
+    given page pools stage (``TransferEngine.stage``), for the whole
+    run."""
 
-    def __init__(self, transfer):
+    def __init__(self, pools):
         self.counts = Counter()
+        for pool in pools:
+            self._note(pool.transfer)
+
+    def _note(self, transfer) -> None:
         stage = transfer.stage
 
         def noted(pids):
@@ -191,29 +181,6 @@ class StagedSizes:
         transfer.stage = noted
 
 
-def warm(engine, cfg: dict, mix: dict, warm_reqs, staged: StagedSizes
-         ) -> None:
-    """Bring the served path to its steady state: fill the slab when it
-    holds the fleet, read every page no request would (``fault_all``),
-    and serve the warm-up requests, which cover every model (each model's
-    head uploads at its first request).  The second pass starts its
-    callers together from the steady state, as the window does, so the
-    window's first batches find their programs compiled too.  Last, the
-    gathers from staged page stacks are compiled for sizes near those
-    the warm-up staged (:func:`warm_staged_gathers`)."""
-    from loop import ClosedLoop
-    store = engine.server.store
-    if mix["slab"]["kind"] == "fleet":
-        for m in cfg["popularity"]:
-            engine.server.access_pages_grouped(m, store.model_pages(m))
-    store.fault_all()
-    ClosedLoop(engine, warm_reqs, mix["callers"]).serve_count(
-        len(warm_reqs))
-    ClosedLoop(engine, warm_reqs, mix["callers"]).serve_count(
-        2 * mix["callers"])
-    warm_staged_gathers(engine.server.device_pool, set(staged.counts))
-
-
 #: how far from a staged size seen in warm-up the window's sizes stray
 STAGED_SPREAD = 8
 
@@ -222,10 +189,11 @@ def warm_staged_gathers(pool, sizes) -> None:
     """Compile the transfer engine's gather from a staged stack
     (``PendingGroup.dev[rows]``, one program per stack size and index
     bucket) for every size within :data:`STAGED_SPREAD` of one the
-    warm-up staged.  A slab smaller than the fleet stages groups whose
-    size settles into a cycle but now and then strays by a page or two;
-    without this each new size compiles inside the window, and the run
-    is not correct (``window_compiles``)."""
+    warm-up staged, on the pool's device.  A slab smaller than the fleet
+    stages groups whose size settles into a cycle but now and then
+    strays by a page or two; without this each new size compiles inside
+    the window, and the run is not correct (``window_compiles``)."""
+    import jax
     import jax.numpy as jnp
     from repro.kernels.dedup_embedding import LANES
 
@@ -238,6 +206,8 @@ def warm_staged_gathers(pool, sizes) -> None:
     for s in sorted(sizes):
         for k in range(max(1, s - STAGED_SPREAD), s + STAGED_SPREAD + 1):
             stack = jnp.zeros((k, pool.page_rows, LANES), pool.dtype)
+            if pool.device is not None:
+                stack = jax.device_put(stack, pool.device)
             buckets = {pow2(m) for m in range(max(1, k - STAGED_SPREAD),
                                                k + 1)}
             for b in sorted(buckets):
@@ -275,6 +245,15 @@ def cell_metrics(spec: dict, cell: dict, traced: bool):
             if "workloads" not in m or cell["name"] in m["workloads"]]
 
 
+def sample(done, k: int, seed: int) -> list:
+    """``k`` answered requests drawn from the seed (all when fewer)."""
+    from traffic import SAMPLE, seed_rng
+    if len(done) <= k:
+        return list(done)
+    idx = seed_rng(seed, SAMPLE).choice(len(done), k, replace=False)
+    return [done[i] for i in sorted(idx)]
+
+
 def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
     """One run; returns the result object (also printed).  ``fault``, for
     the harness's own tests, is called with the engine once it is built
@@ -287,6 +266,7 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
     args = ap.parse_args(argv)
     spec, cell, cfg, mix = load_cell(args.workload)
     sys.path.insert(0, os.path.join(ROOT, "src"))
+    kind = kind_module(cfg.get("kind", "embedding"))
     import jax
     if require_tpu:
         devices = tpu_devices(cell["chips"])
@@ -298,9 +278,7 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
          f"count={len(devices)} compile_cache={cache}")
     events = CompileEvents()
 
-    import check
-    import fleet
-    import traffic
+    from kinds import device_pools
     from loop import ClosedLoop
 
     # ---------------------------------------------------------- set-up --
@@ -311,32 +289,29 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
             os.remove(db_path + suffix)
     url = "sqlite:///" + db_path
     t = time.perf_counter()
-    heads, token_class = fleet.build_fleet(cfg, args.seed, url)
-    ref_heads = {m: h.copy() for m, h in heads.items()}
+    state = kind.build(cfg, args.seed, url)
     t_build = time.perf_counter() - t
 
     t = time.perf_counter()
-    db, engine = open_engine(url, cfg, mix, heads)
+    db, engine = kind.open_engine(url, cfg, mix, state, devices)
     store = db.store
     server = engine.server
-    cap = server.device_pool.capacity
-    mode = server.device_pool.mode()
-    if require_tpu and mode != "pallas":
-        raise RuntimeError(f"slab kernel mode is {mode!r}, not 'pallas'")
+    pools = device_pools(server)
+    cap = sum(p.capacity for p in pools)
+    mode = ",".join(sorted({p.mode() for p in pools}))
     if fault is not None:
         fault(engine)
-    staged = StagedSizes(server.device_pool.transfer)
+    staged = StagedSizes(pools)
     t_reopen = time.perf_counter() - t
 
     t = time.perf_counter()
-    window_reqs = traffic.make_requests(mix, cfg, token_class, args.seed,
-                                        mix["pregenerate"])
-    warm_reqs = traffic.make_requests(mix, cfg, token_class, args.seed,
-                                      mix["warmup_requests"],
-                                      stream=traffic.WARMUP)
-    warm(engine, cfg, mix, warm_reqs, staged)
+    window_reqs, warm_reqs = kind.requests(cfg, mix, state, args.seed)
+    kind.warm(engine, cfg, mix, warm_reqs)
+    for pool in pools:
+        warm_staged_gathers(pool, set(staged.counts))
     staged.counts.clear()
-    loop = ClosedLoop(engine, window_reqs, mix["callers"])
+    loop = ClosedLoop(engine, window_reqs, mix["callers"], kind.send,
+                      kind.answer)
     gc.collect()
     gc.freeze()
     t_warm = time.perf_counter() - t
@@ -345,9 +320,8 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
     spans = None
     trace_dir = os.path.join(SCRATCH, "trace", cell["name"])
     if args.trace:
-        from spans import Spans
         shutil.rmtree(trace_dir, ignore_errors=True)
-        spans = Spans(engine, db)
+        spans = kind.spans(engine, db)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
@@ -360,7 +334,7 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
     # ---------------------------------------------------------- window --
     if args.trace:
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        with spans.window():
+        with jax.profiler.TraceAnnotation("bench.window"):
             t0, t_end, done = loop.serve_for(args.seconds)
         jax.profiler.stop_trace()
     else:
@@ -371,8 +345,7 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
     stats = dev.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
     delta = {k: c1[k] - c0[k] for k in c0}
-    failed_batches = (delta["dense_fallbacks"] + delta["degraded_batches"]
-                      + (delta["batches"] - delta["device_batches"]))
+    failed_batches = kind.failed_batches(delta)
     window_compiles = e1[0] - e0[0]
     sizes = ",".join(f"{k}x{v}" for k, v in sorted(staged.counts.items()))
     info(f"[window] seconds={t_end - t0} answered={len(done)} "
@@ -386,43 +359,35 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
          f"degraded_batches={delta['degraded_batches']} "
          f"pool_hits={delta['hits']} pool_misses={delta['misses']}")
     store_bytes = committed_page_bytes(db_path)
-    variants = len(cfg["popularity"])
-    gather_ids = list(spans.gather_ids) if spans is not None else []
-    picked = check.sample(done, mix["sample_requests"], args.seed)
-    answered = [(d.request.docs.shape, d.sent, d.done) for d in done]
+    fields = kind.fields(cfg, mix, state, done, spans)
+    picked = sample(done, mix["sample_requests"], args.seed)
+    answered = [(d.sent, d.done) for d in done]
 
     # free the program's state before the reference runs
-    del engine, server, loop, store, spans, staged
+    del engine, server, pools, loop, store, spans, staged
     db.close()
     del db
     gc.collect()
 
     # ----------------------------------------------------------- check --
     t = time.perf_counter()
-    result = check.compare(cfg, args.seed, picked, ref_heads)
-    limit = cfg["checks"]["logit_err"]["limit"]
-    info(f"[check] sampled_requests={len(picked)} logits={result['logits']} "
+    checks = kind.check(cfg, args.seed, picked, state)
+    info(f"[check] sampled_requests={len(picked)} "
          f"reference_s={time.perf_counter() - t}")
-    checks = {
-        "logit_err": {"value": result["logit_err"], "limit": limit},
-        "fallback_batches": {"value": failed_batches, "limit": 0},
-        "window_compiles": {"value": window_compiles, "limit": 0},
-    }
-    correct = bool(picked) and limit is not None \
-        and result["logit_err"] <= limit and failed_batches == 0 \
-        and window_compiles == 0
+    checks["fallback_batches"] = {"value": failed_batches, "limit": 0}
+    checks["window_compiles"] = {"value": window_compiles, "limit": 0}
+    correct = bool(picked) and all(
+        c["limit"] is not None and c["value"] <= c["limit"]
+        for c in checks.values())
 
     # --------------------------------------------------------- metrics --
     data = SimpleNamespace(
         cfg=cfg, mix=mix, cell=cell, setup_s=setup_s,
         window_s=t_end - t0,
-        docs=sum(s[0] for s, _, _ in answered),
         requests=len(answered),
-        latencies_s=[b - a for _, a, b in answered],
-        store_bytes=store_bytes, variants=variants,
-        counters=delta, gather_ids=gather_ids,
-        width=cfg[cfg["width_key"]], peaks=None, trace=None, lo=t0,
-        hi=t_end)
+        latencies_s=[b - a for a, b in answered],
+        store_bytes=store_bytes, counters=delta, peaks=None, trace=None,
+        lo=t0, hi=t_end, **fields)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
     out = {"correct": correct, "attempted": len(answered),
@@ -436,8 +401,7 @@ def run(argv=None, *, require_tpu: bool = True, fault=None) -> dict:
         tr = reduce.load(trace_dir)
         data.trace = tr
         data.lo, data.hi = reduce.window_of(tr.spans)
-        busy = reduce.busy_seconds(tr.device, data.lo, data.hi)
-        device["busy_s"] = busy
+        device["busy_s"] = reduce.busy_seconds(tr.device, data.lo, data.hi)
         device["window_s"] = data.hi - data.lo
         breakdown = {
             "device_ops": reduce.top(reduce.op_seconds(

@@ -26,9 +26,10 @@ sys.path.insert(0, BENCH)
 
 
 def counts(cell_name: str, vocab: int, n: int, seed: int, tmp: str) -> dict:
-    import fleet
+    import jax
     import run
     import traffic
+    from kinds import embedding as kind
     from loop import ClosedLoop
 
     _, cell, cfg, mix = run.load_cell(cell_name)
@@ -37,15 +38,15 @@ def counts(cell_name: str, vocab: int, n: int, seed: int, tmp: str) -> dict:
                                              * scale))
     cfg["vocab_size"] = vocab
     url = "sqlite:///" + os.path.join(tmp, f"{cell_name}.{seed}.db")
-    heads, tcs = fleet.build_fleet(cfg, seed, url)
-    db, engine = run.open_engine(url, cfg, mix, heads)
+    state = kind.build(cfg, seed, url)
+    db, engine = kind.open_engine(url, cfg, mix, state, jax.devices())
     store, server = db.store, engine.server
-    run.warm(engine, cfg, mix, traffic.make_requests(
-        mix, cfg, tcs, seed, mix["warmup_requests"], traffic.WARMUP),
-        run.StagedSizes(server.device_pool.transfer))
-    reqs = traffic.make_requests(mix, cfg, tcs, seed, n)
+    _, warmup = kind.requests(cfg, mix, state, seed)
+    kind.warm(engine, cfg, mix, warmup)
+    reqs = traffic.make_requests(mix, cfg, state.token_class, seed, n)
     h0, m0 = server.pool.hits, server.pool.misses
-    done = ClosedLoop(engine, reqs, mix["callers"]).serve_count(n)
+    done = ClosedLoop(engine, reqs, mix["callers"], kind.send,
+                      kind.answer).serve_count(n)
     h1, m1 = server.pool.hits, server.pool.misses
     db.close()
     return {"seed": seed, "touched_row_blocks": cfg["touched_row_blocks"],

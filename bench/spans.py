@@ -45,24 +45,22 @@ class Spans:
 
     def __init__(self, engine, db):
         import repro.serving.engine as engine_mod
+        from kinds import device_pools
         server = engine.server
-        pool = server.device_pool
         self.gather_ids: List[int] = []   # ids per gather call, in order
         _wrap(engine, "submit", "bench.submit")
         _wrap(engine, "run", "bench.engine.run")
         _wrap(server, "access_pages_grouped", "bench.fetch")
         _wrap(db.backend, "get_pages", "bench.storage_fetch")
-        _wrap(pool.transfer, "stage", "bench.stage")
-        _wrap(pool.transfer, "load_group", "bench.transfer_commit")
-        if server.pool.on_load is not None:
-            _wrap(server.pool, "on_load", "bench.page_load")
+        for pool in device_pools(server):
+            _wrap(pool.transfer, "stage", "bench.stage")
+            _wrap(pool.transfer, "load_group", "bench.transfer_commit")
+        # a sharded pool's buffer pools are per shard
+        for bp in getattr(server.device_pool, "buffer_pools", [server.pool]):
+            if bp.on_load is not None:
+                _wrap(bp, "on_load", "bench.page_load")
         _wrap(server, "prestage", "bench.prestage")
         _wrap(server, "device_gather_rows", "bench.gather",
               note=lambda model, tensor, rows, *a, **kw:
               self.gather_ids.append(len(rows)))
         _wrap(engine_mod, "_tok_logits", "bench.head")
-
-    @staticmethod
-    def window():
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation("bench.window")
